@@ -625,6 +625,18 @@ def bench_serve_spec(smoke: bool = False) -> None:
         )
 
 
+def _prediction(v: dict) -> str:
+    """The roofline prediction next to a measured row, or why there is none
+    (serve_sweep predicts only on a device kind with published peaks)."""
+    pred = v["predicted_step_ms"]
+    if pred is None:
+        return f"pred=none (no peaks for {v['device']['kind']})"
+    return (
+        f"pred={pred:.3f}ms ({v['predicted_bottleneck']}-bound) "
+        f"ratio={v['itl_p50_s']*1e3/pred:.0f}x"
+    )
+
+
 def bench_serve_sharded(smoke: bool = False) -> None:
     """B15: tensor-parallel sharded stepping vs the single-device step.
 
@@ -679,9 +691,7 @@ def bench_serve_sharded(smoke: bool = False) -> None:
             v["wall_s"] * 1e6,
             f"{v['tokens_per_s']:.1f} tok/s "
             f"itl_p50={v['itl_p50_s']*1e3:.1f}ms "
-            f"pred={v['predicted_step_ms']:.3f}ms "
-            f"({v['predicted_bottleneck']}-bound) "
-            f"ratio={v['itl_p50_s']*1e3/v['predicted_step_ms']:.0f}x "
+            f"{_prediction(v)} "
             f"decode_traces={v['decode_traces']} "
             f"chunk_traces={v['chunk_traces']} devices={v['mesh_devices']}",
         )
@@ -888,7 +898,7 @@ def bench_distributed(smoke: bool = False) -> None:
 
     from repro.core import Memento
 
-    mp = multiprocessing.get_context("fork")
+    mp = multiprocessing.get_context("spawn")
     n_tasks = 8 if smoke else 32
     delay = 0.02 if smoke else 0.15
     points = (1, 2) if smoke else (1, 2, 4)
@@ -986,9 +996,6 @@ def main(smoke: bool = False) -> None:
     print("name,us_per_call,derived")
     bench_matrix_expansion(smoke)
     if not smoke:
-        # Forks worker processes, so it must run before anything imports
-        # jax (B4 onward) or leaves thread pools behind (B2/B3): forking a
-        # multithreaded XLA process is the documented deadlock case.
         bench_distributed()
     bench_parallel_speedup(smoke)
     bench_cache_speedup(smoke=smoke)
@@ -1036,6 +1043,9 @@ if __name__ == "__main__":
         help="print rows only, do not persist a BENCH_<n>.json record",
     )
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     if args.distributed_smoke:
         print("name,us_per_call,derived")
         bench_distributed(smoke=True)

@@ -16,6 +16,11 @@ are asserted token-for-token identical.
 ``--fast`` swaps the real serve model for a synthetic workload (no compile;
 finishes in seconds) — the orchestration, dashboard, and analysis paths are
 identical.
+
+The stand-in hosts are processes on one machine, and an accelerator chip
+belongs to one process at a time, so the demo pins every process to the CPU
+(``JAX_PLATFORMS=cpu``, inherited by the spawned workers). Real launcher
+hosts each drive their own chips.
 """
 import argparse
 import multiprocessing
@@ -26,11 +31,13 @@ import sys
 import tempfile
 import time
 
-import repro.core as memento
-from repro.analysis import AnalysisNotificationProvider, Dashboard, compare
-from repro.analysis.metrics import MetricFrame
-from repro.core import DistributedConfig, RunnerConfig
-from repro.experiments import serve_matrix, serve_sweep
+os.environ["JAX_PLATFORMS"] = "cpu"  # before anything imports jax
+
+import repro.core as memento  # noqa: E402
+from repro.analysis import AnalysisNotificationProvider, Dashboard, compare  # noqa: E402
+from repro.analysis.metrics import MetricFrame  # noqa: E402
+from repro.core import DistributedConfig, RunnerConfig  # noqa: E402
+from repro.experiments import serve_matrix, serve_sweep  # noqa: E402
 
 
 def fast_sweep(ctx):
@@ -115,7 +122,7 @@ def main() -> int:
     url = dash.start()
     print(f"dashboard: {url}   (state: {url}/api/state)")
 
-    mp = multiprocessing.get_context("fork")
+    mp = multiprocessing.get_context("spawn")
     procs = [
         mp.Process(target=worker, args=(root, f"host{i}", args.fast, journal))
         for i in range(2)

@@ -10,7 +10,10 @@ cluster-wide per-host progress line, and ends up with the full ResultSet.
 
 ``--serve`` swaps the toy task for a real (smoke-scale) serving sweep via
 ``experiments.serve_sweep_distributed`` — the distributed serve sweep from
-the ROADMAP. One model compile per host, so expect ~a minute on CPU.
+the ROADMAP. One model compile per host, so expect ~a minute on CPU. The
+stand-in hosts are processes on one machine, and an accelerator chip belongs
+to one process at a time, so ``--serve`` pins every host to the CPU
+(``JAX_PLATFORMS=cpu``); real launcher hosts each drive their own chips.
 """
 from __future__ import annotations
 
@@ -53,7 +56,7 @@ def main_toy(n_hosts: int) -> None:
 
     root = tempfile.mkdtemp(prefix="memento_distributed_")
     print(f"shared dir: {root}  ({n_hosts} worker hosts + this one)")
-    mp = multiprocessing.get_context("fork")
+    mp = multiprocessing.get_context("spawn")
     workers = [
         mp.Process(target=_worker, args=(root, f"host-{i}"))
         for i in range(n_hosts)
@@ -104,6 +107,7 @@ def _serve_worker(root: str, owner: str) -> None:
 
 
 def main_serve(n_hosts: int) -> None:
+    os.environ["JAX_PLATFORMS"] = "cpu"  # inherited by the spawned hosts
     from repro.experiments import serve_sweep_distributed
 
     root = tempfile.mkdtemp(prefix="memento_distserve_")

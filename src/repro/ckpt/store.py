@@ -36,9 +36,7 @@ from repro.core.exceptions import CheckpointError
 
 
 def _flatten_with_paths(tree: Any) -> tuple[list[tuple[str, Any]], Any]:
-    from repro.compat import tree_flatten_with_path
-
-    flat, treedef = tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     out = []
     for path, leaf in flat:
         key = "/".join(str(getattr(p, "key", getattr(p, "idx", p))) for p in path)

@@ -33,6 +33,7 @@ budget, not one. All finalisation decisions happen under one lock.
 from __future__ import annotations
 
 import concurrent.futures as cf
+import multiprocessing
 import os
 import statistics
 import threading
@@ -541,7 +542,12 @@ class Runner:
         if not specs:
             return
         cfg = self.config
-        with cf.ProcessPoolExecutor(max_workers=cfg.resolved_workers()) as pool:
+        # Workers are spawned, never forked: a forked child of a process that
+        # has started JAX inherits its threads and its hold on the device.
+        with cf.ProcessPoolExecutor(
+            max_workers=cfg.resolved_workers(),
+            mp_context=multiprocessing.get_context("spawn"),
+        ) as pool:
             fut_to_spec: dict[cf.Future, tuple[TaskSpec, float, int]] = {}
             for spec in specs:
                 fut = pool.submit(_run_task, self.func, spec, self.checkpoint_root, 1, None, None)

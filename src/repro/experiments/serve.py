@@ -52,7 +52,9 @@ Axes/settings understood by :func:`serve_sweep`:
                          devices (see launch/mesh.py)
   sharding_profile       ShardingProfile for the mesh (default
                          "decode_default")
-  reduced                use the smoke-scale config copy (default True)
+  reduced                use the smoke-scale config copy (default True);
+                         weights are materialised in the config's compute
+                         dtype (bf16 at full width, f32 for smoke configs)
   warmup                 pre-compile per prompt bucket before timing (default True)
   seed                   workload RNG seed (default 0)
 """
@@ -70,7 +72,7 @@ from repro.core.task import Context
 from repro.serve.request import Request
 from repro.serve.plan import pow2_ceil
 from repro.serve.scheduler import Scheduler, SchedulerConfig
-from repro.sharding.rules import ShardingCtx
+from repro.sharding.rules import ShardingCtx, get_profile
 
 # Declarative registration for repro.analysis: the serve metrics worth
 # extracting from sweep results (``Examiner(SERVE_METRIC_SPECS)``). Raw keys
@@ -97,9 +99,9 @@ SERVE_METRIC_SPECS: tuple[MetricSpec, ...] = (
         else v["ttft_p50_s"] * 1e3,
     ),
     MetricSpec("predicted_step_ms", unit="ms"),
-    # Measured inter-token latency over the analytic roofline bound: how
-    # far the smoke-scale CPU run sits above the v5e prediction. Only the
-    # *trend across meshes* is meaningful off-TPU, not the magnitude.
+    # Measured inter-token latency over the analytic roofline bound. The
+    # bound exists only on a device kind with published peaks
+    # (launch/roofline.py PEAKS), so the ratio is None off the chip.
     MetricSpec(
         "roofline_ratio",
         extract=lambda v: (
@@ -189,6 +191,34 @@ def serve_sweep_distributed(
     )
 
 
+def serving_ctx(
+    mesh_shape: tuple[int, int] | None, profile: str = "decode_default"
+) -> ShardingCtx:
+    """The ("data", "model") mesh context for ``mesh_shape`` over the first
+    visible devices, or the null context for one device."""
+    if mesh_shape is None or mesh_shape[0] * mesh_shape[1] == 1:
+        return ShardingCtx.null()
+    from repro.launch.mesh import make_test_mesh
+
+    return ShardingCtx(make_test_mesh(*mesh_shape), get_profile(profile))
+
+
+def init_serving_params(cfg, sctx: ShardingCtx, seed: int) -> Any:
+    """Random weights in the config's compute dtype (f32 weights of a 3B
+    model do not fit a 16 GB chip), materialised at their mesh placement so
+    that no device ever holds an unsharded copy next to its shard."""
+    import jax
+
+    from repro.models import lm
+    from repro.models.schema import init_params, sharding_tree
+
+    schema = lm.model_schema(cfg)
+    return jax.jit(
+        lambda key: init_params(schema, key, dtype=cfg.compute_dtype),
+        out_shardings=sharding_tree(schema, sctx),
+    )(jax.random.PRNGKey(seed))
+
+
 def serve_sweep(ctx: Context) -> dict[str, Any]:
     """Experiment function: drive one serving configuration, return metrics."""
     arch = ctx["arch"]
@@ -198,14 +228,16 @@ def serve_sweep(ctx: Context) -> dict[str, Any]:
     backend = _opt(ctx, "attn_backend", cfg.attn_backend)
     cfg = replace(cfg, attn_backend=backend)
 
-    from repro.models import lm
-    from repro.models.schema import init_params
-
     import jax
 
-    params = init_params(lm.model_schema(cfg), jax.random.PRNGKey(_opt(ctx, "seed", 0)))
+    from repro.models import lm
+
     chunk_budget = _opt(ctx, "chunk_budget", None) or None
     mesh_shape = _mesh_shape_opt(_opt(ctx, "mesh_shape", None))
+    profile = str(_opt(ctx, "sharding_profile", "decode_default"))
+    sctx = serving_ctx(mesh_shape, profile)
+    schema = lm.model_schema(cfg)
+    params = init_serving_params(cfg, sctx, int(_opt(ctx, "seed", 0)))
     sched_cfg = SchedulerConfig(
         n_slots=int(_opt(ctx, "n_slots", 4)),
         cache_len=int(_opt(ctx, "cache_len", 128)),
@@ -223,12 +255,12 @@ def serve_sweep(ctx: Context) -> dict[str, Any]:
         draft_k=int(_opt(ctx, "draft_k", 4)),
         seed=int(_opt(ctx, "seed", 0)),
         mesh_shape=mesh_shape,
-        sharding_profile=str(_opt(ctx, "sharding_profile", "decode_default")),
+        sharding_profile=profile,
     )
     drafter_kind = str(_opt(ctx, "drafter", "ngram"))
     if drafter_kind not in ("ngram", "oracle"):
         raise ValueError(f"unknown drafter {drafter_kind!r}")
-    sched = Scheduler(cfg, params, ShardingCtx.null(), sched_cfg)
+    sched = Scheduler(cfg, params, sctx, sched_cfg)
 
     rng = np.random.default_rng(int(_opt(ctx, "seed", 0)))
     n_req = int(_opt(ctx, "n_requests", 8))
@@ -388,20 +420,29 @@ def serve_sweep(ctx: Context) -> dict[str, Any]:
     # ~min(n_slots, live requests); speculation lifts it by accepted
     # tokens per verify.
     model_steps = decode_steps + spec_steps + spec_replays
-    # Analytic v5e roofline for one decode step at this batch and mesh —
-    # recorded next to the measured latencies so analysis can report the
-    # measured/predicted ratio per mesh (launch/roofline.py).
-    from repro.launch.roofline import predict_decode_step
+    # Analytic roofline for one decode step at this batch and mesh, on a
+    # device with published peaks only — recorded next to the measured
+    # latencies so analysis can report the measured/predicted ratio.
+    from repro.launch.roofline import PEAKS, predict_decode_step
     from repro.models.schema import count_params
 
-    pred = predict_decode_step(
-        cfg,
-        count_params(lm.model_schema(cfg)),
-        batch=sched_cfg.n_slots,
-        mesh_shape=mesh_shape or (1, 1),
-    )
+    dev = jax.devices()[0]
+    pred = None
+    if dev.device_kind in PEAKS:
+        pred = predict_decode_step(
+            cfg,
+            count_params(schema),
+            batch=sched_cfg.n_slots,
+            mesh_shape=mesh_shape or (1, 1),
+            device_kind=dev.device_kind,
+        )
     return {
         "arch": arch,
+        "device": {
+            "platform": dev.platform,
+            "kind": dev.device_kind,
+            "count": jax.device_count(),
+        },
         "attn_backend": backend,
         "n_requests": n_req,
         "generated_tokens": toks,
@@ -444,8 +485,8 @@ def serve_sweep(ctx: Context) -> dict[str, Any]:
         ],
         "mesh": "1x1" if mesh_shape is None else f"{mesh_shape[0]}x{mesh_shape[1]}",
         "mesh_devices": sched.sctx.device_count(),
-        "predicted_step_ms": pred.step_time_lower_bound * 1e3,
-        "predicted_bottleneck": pred.bottleneck,
+        "predicted_step_ms": pred.step_time_lower_bound * 1e3 if pred else None,
+        "predicted_bottleneck": pred.bottleneck if pred else None,
         "paged": sched_cfg.paged,
         "chunk_budget": sched_cfg.chunk_budget,
         "preemption": sched_cfg.preemption,

@@ -117,6 +117,40 @@ def decode_attention(
     )(q, k, v, k_pos, cur_pos[None].astype(jnp.int32))
 
 
+def _paged_init(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def _paged_head_update(h, q, k_ref, v_ref, valid, acc_ref, m_ref, l_ref, sm_scale):
+    """One online-softmax step of kv-head ``h`` against the current page.
+
+    The k/v blocks hold every kv head of the page, ``(1, page, KV, D)``: the
+    TPU compiler needs a block's last two dims tiled or whole, so the head
+    is picked here rather than by the BlockSpec. Row state is 2-D
+    ``(rows, 1)`` per head for the same reason."""
+    k = k_ref[0, :, h, :].astype(F32)  # (page, D)
+    v = v_ref[0, :, h, :].astype(F32)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=F32) * sm_scale
+    s = jnp.where(valid, s, NEG_INF)  # (rows, page)
+    m_prev = m_ref[h]  # (rows, 1)
+    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_cur)
+    p = jnp.exp(s - m_cur)
+    l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())), preferred_element_type=F32
+    )
+    m_ref[h] = m_cur
+
+
+def _paged_flush(o_ref, acc_ref, l_ref):
+    for h in range(o_ref.shape[1]):
+        l = jnp.maximum(l_ref[h], 1e-30)
+        o_ref[0, h] = (acc_ref[h] / l).astype(o_ref.dtype)
+
+
 def _paged_decode_kernel(
     pt_ref, cp_ref,  # scalar prefetch: (B, MP) page table, (B,) cur positions
     q_ref, k_ref, v_ref, o_ref,
@@ -124,48 +158,32 @@ def _paged_decode_kernel(
     *, sm_scale, window, page, n_lp,
 ):
     b = pl.program_id(0)
-    j = pl.program_id(2)  # logical page (innermost: sequential accumulation)
+    j = pl.program_id(1)  # logical page (innermost: sequential accumulation)
 
     @pl.when(j == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _paged_init(acc_ref, m_ref, l_ref)
 
-    q = q_ref[0, 0].astype(F32)  # (G, D)
-    k = k_ref[0, :, 0, :].astype(F32)  # (page, D)
-    v = v_ref[0, :, 0, :].astype(F32)
     cur = cp_ref[b]
-
     # Positional validity from the logical slot index alone: dense slots hold
     # position s; ring slots s < window hold the latest p <= cur with
     # p % window == s (negative -> never written). Tail-page slots past the
     # write head and trash-page blocks fall out as invalid automatically.
-    s_idx = j * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)[0]
+    s_idx = j * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
     if window:
         k_pos = cur - ((cur - s_idx) % window)
         k_pos = jnp.where(s_idx < window, k_pos, -1)
     else:
         k_pos = s_idx
-    valid = (k_pos >= 0) & (k_pos <= cur)
+    valid = (k_pos >= 0) & (k_pos <= cur)  # (1, page)
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=F32) * sm_scale
-    s = jnp.where(valid[None, :], s, NEG_INF)  # (G, page)
-
-    m_prev = m_ref[...]
-    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    alpha = jnp.exp(m_prev - m_cur)
-    p = jnp.exp(s - m_cur[:, None])
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=F32
-    )
-    m_ref[...] = m_cur
+    for h in range(q_ref.shape[1]):
+        q = q_ref[0, h].astype(F32)  # (G, D)
+        _paged_head_update(h, q, k_ref, v_ref, valid, acc_ref, m_ref, l_ref, sm_scale)
 
     @pl.when(j == n_lp - 1)
     def _flush():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        _paged_flush(o_ref, acc_ref, l_ref)
 
 
 def _paged_chunk_kernel(
@@ -175,20 +193,14 @@ def _paged_chunk_kernel(
     *, sm_scale, page, n_lp, G,
 ):
     b = pl.program_id(0)
-    j = pl.program_id(2)  # logical page (innermost: sequential accumulation)
+    j = pl.program_id(1)  # logical page (innermost: sequential accumulation)
 
     @pl.when(j == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _paged_init(acc_ref, m_ref, l_ref)
 
-    q = q_ref[0, 0].astype(F32)  # (C*G, D): query row r = c*G + g
-    k = k_ref[0, :, 0, :].astype(F32)  # (page, D)
-    v = v_ref[0, :, 0, :].astype(F32)
     start = start_ref[b]
-    CG = q.shape[0]
-
+    CG = q_ref.shape[2]
     # Dense chunked prefill: logical slot s holds position s; query row r is
     # chunk token c = r // G at absolute position start + c. Trash-backed
     # table entries and the chunk's own padded tail sit at k_pos > q_pos and
@@ -197,23 +209,46 @@ def _paged_chunk_kernel(
     q_pos = start + jax.lax.broadcasted_iota(jnp.int32, (CG, page), 0) // G
     valid = k_pos <= q_pos  # (CG, page)
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=F32) * sm_scale
-    s = jnp.where(valid, s, NEG_INF)  # (CG, page)
-
-    m_prev = m_ref[...]
-    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    alpha = jnp.exp(m_prev - m_cur)
-    p = jnp.exp(s - m_cur[:, None])
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=F32
-    )
-    m_ref[...] = m_cur
+    for h in range(q_ref.shape[1]):
+        q = q_ref[0, h].astype(F32)  # (C*G, D): query row r = c*G + g
+        _paged_head_update(h, q, k_ref, v_ref, valid, acc_ref, m_ref, l_ref, sm_scale)
 
     @pl.when(j == n_lp - 1)
     def _flush():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        _paged_flush(o_ref, acc_ref, l_ref)
+
+
+def _paged_call(kernel, q, k_pool, v_pool, page_table, vec, *, n_lp, interpret):
+    """Grid ``(B, n_lp)``: one program per (slot, logical page), all kv
+    heads per program, so each physical page is DMA'd once. The page table
+    rides as a scalar-prefetch operand and the k/v BlockSpecs fetch physical
+    page ``page_table[b, j]`` at grid step ``(b, j)``."""
+    if interpret is None:
+        from repro.kernels.ops import default_interpret
+
+        interpret = default_interpret()
+    B, KV, R, D = q.shape
+    page = k_pool.shape[1]
+    assert n_lp <= page_table.shape[1], (n_lp, page_table.shape)
+    kv_spec = pl.BlockSpec((1, page, KV, D), lambda b, j, pt, vc: (pt[b, j], 0, 0, 0))
+    q_spec = pl.BlockSpec((1, KV, R, D), lambda b, j, pt, vc: (b, 0, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, n_lp),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        scratch_shapes=[
+            pltpu.VMEM((KV, R, D), F32),
+            pltpu.VMEM((KV, R, 1), F32),
+            pltpu.VMEM((KV, R, 1), F32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KV, R, D), q.dtype),
+        interpret=interpret,
+    )(page_table.astype(jnp.int32), vec.astype(jnp.int32), q, k_pool, v_pool)
 
 
 def paged_chunk_attention(
@@ -235,39 +270,15 @@ def paged_chunk_attention(
     index and the chunk start, so the kernel never materialises a gathered
     cache copy or a mask input.
     """
-    if interpret is None:
-        from repro.kernels.ops import default_interpret
-
-        interpret = default_interpret()
-    B, KV, CG, D = q.shape
-    page = k_pool.shape[1]
+    D = q.shape[-1]
+    assert q.shape[2] % group == 0, (q.shape, group)
     sm = sm_scale if sm_scale is not None else D ** -0.5
-    assert n_lp <= page_table.shape[1], (n_lp, page_table.shape)
-    assert CG % group == 0, (CG, group)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, KV, n_lp),
-        in_specs=[
-            pl.BlockSpec((1, 1, CG, D), lambda b, h, j, pt, st: (b, h, 0, 0)),
-            pl.BlockSpec((1, page, 1, D), lambda b, h, j, pt, st: (pt[b, j], 0, h, 0)),
-            pl.BlockSpec((1, page, 1, D), lambda b, h, j, pt, st: (pt[b, j], 0, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, CG, D), lambda b, h, j, pt, st: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((CG, D), F32),
-            pltpu.VMEM((CG,), F32),
-            pltpu.VMEM((CG,), F32),
-        ],
+    kernel = functools.partial(
+        _paged_chunk_kernel, sm_scale=sm, page=k_pool.shape[1], n_lp=n_lp, G=group
     )
-    return pl.pallas_call(
-        functools.partial(
-            _paged_chunk_kernel, sm_scale=sm, page=page, n_lp=n_lp, G=group
-        ),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, CG, D), q.dtype),
-        interpret=interpret,
-    )(page_table.astype(jnp.int32), start.astype(jnp.int32), q, k_pool, v_pool)
+    return _paged_call(
+        kernel, q, k_pool, v_pool, page_table, start, n_lp=n_lp, interpret=interpret
+    )
 
 
 def paged_decode_attention(
@@ -281,41 +292,16 @@ def paged_decode_attention(
 ) -> jax.Array:
     """Flash decode over page-table-gathered KV blocks.
 
-    One program per (slot, kv-head, logical page); the page table rides as a
-    scalar-prefetch operand so the k/v BlockSpecs DMA physical page
-    ``page_table[b, j]`` for grid step ``(b, h, j)``. ``n_lp`` bounds the
-    logical pages attended — ``ceil(window / page)`` for ring-folded
-    windowed layers (a bounded working set), the full table width for dense.
+    ``n_lp`` bounds the logical pages attended — ``ceil(window / page)`` for
+    ring-folded windowed layers (a bounded working set), the full table
+    width for dense.
     """
-    if interpret is None:
-        from repro.kernels.ops import default_interpret
-
-        interpret = default_interpret()
-    B, KV, G, D = q.shape
-    page = k_pool.shape[1]
+    D = q.shape[-1]
     sm = sm_scale if sm_scale is not None else D ** -0.5
-    assert n_lp <= page_table.shape[1], (n_lp, page_table.shape)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, KV, n_lp),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, j, pt, cp: (b, h, 0, 0)),
-            pl.BlockSpec((1, page, 1, D), lambda b, h, j, pt, cp: (pt[b, j], 0, h, 0)),
-            pl.BlockSpec((1, page, 1, D), lambda b, h, j, pt, cp: (pt[b, j], 0, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, j, pt, cp: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, D), F32),
-            pltpu.VMEM((G,), F32),
-            pltpu.VMEM((G,), F32),
-        ],
+    kernel = functools.partial(
+        _paged_decode_kernel, sm_scale=sm, window=window, page=k_pool.shape[1],
+        n_lp=n_lp,
     )
-    return pl.pallas_call(
-        functools.partial(
-            _paged_decode_kernel, sm_scale=sm, window=window, page=page, n_lp=n_lp
-        ),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
-        interpret=interpret,
-    )(page_table.astype(jnp.int32), cur_pos.astype(jnp.int32), q, k_pool, v_pool)
+    return _paged_call(
+        kernel, q, k_pool, v_pool, page_table, cur_pos, n_lp=n_lp, interpret=interpret
+    )

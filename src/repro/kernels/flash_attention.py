@@ -15,6 +15,10 @@ TPU adaptation of the FlashAttention-2 schedule [arXiv:2307.08691]:
 Backward follows FA-2: LSE saved from fwd; one kernel computes dQ (k-inner
 loop), a second computes dK/dV (q-inner loop). delta = rowsum(dO * O) is
 computed outside in jnp (cheap, bandwidth-bound).
+
+Per-row values (the running max and sum, LSE, delta) are 2-D ``(rows, 1)``
+columns: the TPU compiler needs a block's last two dims tiled by (8, 128)
+or equal to the array's, which a 1-D row vector cannot satisfy.
 """
 from __future__ import annotations
 
@@ -78,12 +82,12 @@ def _fwd_kernel(
         msk = _mask(qi, ki, blk_q, blk_k, causal, window, q_offset)
         s = jnp.where(msk, s, NEG_INF)
 
-        m_prev = m_ref[...]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        m_prev = m_ref[...]  # (blk_q, 1)
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur[:, None])
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+        p = jnp.exp(s - m_cur)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=F32
         )
         m_ref[...] = m_cur
@@ -91,7 +95,7 @@ def _fwd_kernel(
     @pl.when(ki == n_k - 1)
     def _flush():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
         lse_ref[0] = (m_ref[...] + jnp.log(l)).astype(lse_ref.dtype)
 
 
@@ -129,16 +133,16 @@ def flash_attention_fwd(
         ],
         out_specs=[
             pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, blk_q), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, blk_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
-            jax.ShapeDtypeStruct((BH, Sq), F32),
+            jax.ShapeDtypeStruct((BH, Sq, 1), F32),
         ],
         scratch_shapes=[
             pltpu.VMEM((blk_q, D), F32),
-            pltpu.VMEM((blk_q,), F32),
-            pltpu.VMEM((blk_q,), F32),
+            pltpu.VMEM((blk_q, 1), F32),
+            pltpu.VMEM((blk_q, 1), F32),
         ],
         interpret=interpret,
     )(q, k, v)
@@ -174,9 +178,9 @@ def _dq_kernel(
         delta = delta_ref[0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=F32) * sm_scale
         msk = _mask(qi, ki, blk_q, blk_k, causal, window, q_offset)
-        p = jnp.where(msk, jnp.exp(s - lse[:, None]), 0.0)
+        p = jnp.where(msk, jnp.exp(s - lse), 0.0)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())), preferred_element_type=F32)
-        ds = p * (dp - delta[:, None]) * sm_scale
+        ds = p * (dp - delta) * sm_scale
         acc_ref[...] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())), preferred_element_type=F32)
 
     @pl.when(ki == n_k - 1)
@@ -212,10 +216,10 @@ def _dkv_kernel(
         delta = delta_ref[0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=F32) * sm_scale
         msk = _mask(qi, ki, blk_q, blk_k, causal, window, q_offset)
-        p = jnp.where(msk, jnp.exp(s - lse[:, None]), 0.0)  # (blk_q, blk_k)
+        p = jnp.where(msk, jnp.exp(s - lse), 0.0)  # (blk_q, blk_k)
         dv_acc[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())), preferred_element_type=F32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())), preferred_element_type=F32)
-        ds = p * (dp - delta[:, None]) * sm_scale
+        ds = p * (dp - delta) * sm_scale
         dk_acc[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())), preferred_element_type=F32)
 
     @pl.when(qi == n_q - 1)
@@ -239,7 +243,7 @@ def flash_attention_bwd(
     blk_k = min(blk_k, Sk)
     sm = sm_scale if sm_scale is not None else D ** -0.5
     n_q, n_k = Sq // blk_q, Sk // blk_k
-    delta = jnp.sum(do.astype(F32) * o.astype(F32), axis=-1)  # (BH, Sq)
+    delta = jnp.sum(do.astype(F32) * o.astype(F32), axis=-1, keepdims=True)  # (BH, Sq, 1)
 
     dq = pl.pallas_call(
         functools.partial(
@@ -252,8 +256,8 @@ def flash_attention_bwd(
             pl.BlockSpec((1, blk_k, D), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, blk_k, D), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, blk_q), lambda b, i, j: (b, i)),
-            pl.BlockSpec((1, blk_q), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, blk_q, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, blk_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
@@ -272,8 +276,8 @@ def flash_attention_bwd(
             pl.BlockSpec((1, blk_k, D), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, blk_k, D), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, blk_q, D), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, blk_q), lambda b, j, i: (b, i)),
-            pl.BlockSpec((1, blk_q), lambda b, j, i: (b, i)),
+            pl.BlockSpec((1, blk_q, 1), lambda b, j, i: (b, i, 0)),
+            pl.BlockSpec((1, blk_q, 1), lambda b, j, i: (b, i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, blk_k, D), lambda b, j, i: (b, j, 0)),

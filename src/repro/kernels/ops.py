@@ -7,9 +7,8 @@
 * ``rglru_op`` / ``mlstm_op`` — recurrence wrappers.
 * ``moe_gmm_op`` — grouped matmul with block padding.
 
-``interpret`` defaults to True off-TPU (this container validates kernels on
-CPU via the Pallas interpreter); on a TPU backend the same code compiles to
-Mosaic.
+``interpret`` is True on the CPU backend (tests validate the kernels through
+the Pallas interpreter); on a TPU backend the same code compiles to Mosaic.
 """
 from __future__ import annotations
 
@@ -26,7 +25,15 @@ from . import rglru as _rg
 
 
 def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """True on the CPU, False on a TPU, and an error on any other backend:
+    an interpreted kernel on an accelerator would hide the device."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas kernels compile for 'tpu' or interpret on 'cpu'; the "
+            f"default backend is {backend!r}"
+        )
+    return backend == "cpu"
 
 
 # ==========================================================================
@@ -178,8 +185,6 @@ def paged_decode_attention_sharded(
     localize_pages: bool = False,
 ) -> jax.Array:
     """``paged_decode_attention_op`` run per-shard under ``mesh``."""
-    from repro.compat import shard_map
-
     rows_local = k_pool.shape[0] // (
         mesh.shape["data"] if localize_pages else 1
     )
@@ -191,12 +196,12 @@ def paged_decode_attention_sharded(
             qs, ks, vs, pt, pos, n_lp=n_lp, window=window
         )
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(q_spec, pool_spec, pool_spec, table_spec, vec_spec),
         out_specs=q_spec,
-        check=False,
+        check_vma=False,
     )(q, k_pool, v_pool, page_table, cur_pos)
 
 
@@ -218,17 +223,15 @@ def paged_chunk_attention_sharded(
     are single-slot (B == 1), so only the head/model axis partitions —
     the caller falls back to the XLA gather path when the pool is
     data-partitioned."""
-    from repro.compat import shard_map
-
     def body(qs, ks, vs, pt, st):
         return paged_chunk_attention_op(qs, ks, vs, pt, st, n_lp=n_lp)
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(q_spec, pool_spec, pool_spec, table_spec, vec_spec),
         out_specs=q_spec,
-        check=False,
+        check_vma=False,
     )(q, k_pool, v_pool, page_table, start)
 
 

@@ -1,9 +1,9 @@
-"""Roofline model for TPU v5e from compiled dry-run artifacts.
+"""Roofline model from compiled dry-run artifacts, with per-device peaks.
 
-Hardware constants (assignment-specified):
-    peak bf16 compute: 197 TFLOP/s per chip
-    HBM bandwidth:     819 GB/s per chip
-    ICI link:          ~50 GB/s per link
+Peaks come from :data:`PEAKS`, one entry per ``jax.Device.device_kind``
+with its published source; a device kind missing from the table is an
+error, never a default. The dry run and the decode predictor target the
+TPU v5e (``"TPU v5 lite"``).
 
 Sources:
   * ``compiled.cost_analysis()`` -> HLO_FLOPs, HLO_bytes. On this backend the
@@ -26,9 +26,51 @@ import re
 from dataclasses import asdict, dataclass, field
 from typing import Any
 
-PEAK_FLOPS = 197e12  # bf16 / chip
-HBM_BW = 819e9  # bytes/s / chip
-ICI_BW = 50e9  # bytes/s / link (we model one serialized link — conservative)
+
+@dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks of one device kind."""
+
+    bf16_flops: float  # FLOP/s
+    hbm_bw: float  # bytes/s
+    ici_bw: float  # bytes/s of chip-to-chip interconnect, all links
+    ici_links: int
+    source: str
+
+    @property
+    def ici_link_bw(self) -> float:
+        """Bytes/s of one link: the collective term models one serialized
+        link (conservative)."""
+        return self.ici_bw / self.ici_links
+
+
+V5E = "TPU v5 lite"
+
+# Keyed by ``jax.Device.device_kind``.
+PEAKS: dict[str, DevicePeaks] = {
+    V5E: DevicePeaks(
+        bf16_flops=197e12,
+        hbm_bw=819e9,
+        ici_bw=1600e9 / 8,  # 1,600 Gbit/s
+        ici_links=4,  # assumed split: 50 GB/s per link
+        source=(
+            'Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, '
+            "393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s "
+            "chip-to-chip interconnect"
+        ),
+    ),
+}
+
+
+def device_peaks(device_kind: str) -> DevicePeaks:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(PEAKS)})"
+        ) from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -262,18 +304,23 @@ class Roofline:
     per_device_memory_bytes: float = 0.0
     op_bytes: dict[str, float] = field(default_factory=dict)
     op_counts: dict[str, int] = field(default_factory=dict)
+    device_kind: str = V5E
+
+    @property
+    def peaks(self) -> DevicePeaks:
+        return device_peaks(self.device_kind)
 
     @property
     def t_compute(self) -> float:
-        return self.hlo_flops_per_device / PEAK_FLOPS
+        return self.hlo_flops_per_device / self.peaks.bf16_flops
 
     @property
     def t_memory(self) -> float:
-        return self.hlo_bytes_per_device / HBM_BW
+        return self.hlo_bytes_per_device / self.peaks.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes_per_device / ICI_BW
+        return self.collective_bytes_per_device / self.peaks.ici_link_bw
 
     @property
     def bottleneck(self) -> str:
@@ -298,7 +345,7 @@ class Roofline:
     @property
     def roofline_fraction(self) -> float:
         """Achievable MFU bound = useful compute time / bound step time."""
-        t_useful = self.model_flops / (self.chips * PEAK_FLOPS)
+        t_useful = self.model_flops / (self.chips * self.peaks.bf16_flops)
         lb = self.step_time_lower_bound
         return t_useful / lb if lb else 0.0
 
@@ -332,6 +379,7 @@ def predict_decode_step(
     batch: int,
     mesh_shape: tuple[int, int] = (1, 1),
     dtype_bytes: int = 2,
+    device_kind: str = V5E,
 ) -> Roofline:
     """Analytic roofline for ONE sharded decode step (no HLO needed).
 
@@ -363,6 +411,7 @@ def predict_decode_step(
         collective_bytes_per_device=coll,
         model_flops=2.0 * n_params * batch,
         per_device_memory_bytes=weight_bytes,
+        device_kind=device_kind,
     )
 
 

@@ -27,15 +27,12 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import F32, _act, cdt
 from repro.models.schema import ParamSpec
 from repro.sharding.rules import ShardingCtx, constrain
-
-from repro.compat import shard_map as _compat_shard_map
-
-from jax.sharding import PartitionSpec as P
 
 
 # --------------------------------------------------------------------------
@@ -232,12 +229,12 @@ def moe_ffn(
                 stats = jax.lax.pmean(stats, ep_axes)
             return y, stats
 
-        out, stats = _compat_shard_map(
+        out, stats = jax.shard_map(
             shard_fn,
             mesh=mesh,
             in_specs=in_specs,
             out_specs=out_specs,
-            check=False,
+            check_vma=False,
         )(x_flat, p)
 
     E = mo.n_experts

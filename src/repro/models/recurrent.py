@@ -506,17 +506,15 @@ def _shard_map_batched(fn, sctx: ShardingCtx, batch_dim_size: int):
         return fn
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map as _sm
-
     bspec = P(tuple(axes))
 
     def wrapped(gates, r, state):
-        return _sm(
+        return jax.shard_map(
             fn,
             mesh=mesh,
             in_specs=(bspec, P(), jax.tree.map(lambda _: bspec, state)),
             out_specs=(bspec, jax.tree.map(lambda _: bspec, state)),
-            check=False,
+            check_vma=False,
         )(gates, r, state)
 
     return wrapped

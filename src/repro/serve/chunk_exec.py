@@ -65,7 +65,7 @@ def prefill_chunk_step(s) -> bool:
     if n_real < cp.bucket:
         toks = np.concatenate([toks, np.zeros(cp.bucket - n_real, np.int32)])
     args = [
-        s._states["layers"], s._states["pos"], s._put(toks[None, :]),
+        s.params, s._states["layers"], s._states["pos"], s._put(toks[None, :]),
         jnp.asarray(slot, jnp.int32), jnp.asarray(start, jnp.int32),
         jnp.asarray(n_real, jnp.int32),
     ]

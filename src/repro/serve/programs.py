@@ -16,9 +16,11 @@ chunk, (k, page) buckets for verify. The Python bodies run only when jit
 the registry count: tests pin them to prove the bucket sets are closed and
 mesh-independent.
 
-Nothing here owns scheduling state. The registry reads model config,
-sharding context, and (sharded) params; slots, queues, and pages belong to
-the executor and memory layers.
+Nothing here owns scheduling state. The registry reads model config and
+sharding context; slots, queues, and pages belong to the executor and
+memory layers. Every program takes the weights as an argument: a jitted
+function that closed over them would embed them in the program as
+constants, gigabytes of them at full width.
 """
 from __future__ import annotations
 
@@ -29,7 +31,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.models import lm
 from repro.serve.cache import (
@@ -120,7 +121,6 @@ class ProgramRegistry:
         self,
         cfg: ModelConfig,
         sctx: ShardingCtx,
-        params: Any,
         *,
         cache_len: int,
         layouts: Any,
@@ -131,7 +131,6 @@ class ProgramRegistry:
     ):
         self.cfg = cfg
         self.sctx = sctx
-        self.params = params
         self._cache_len = cache_len
         self._layouts = layouts
         self._caps = caps
@@ -237,8 +236,8 @@ class ProgramRegistry:
         self.admit = jax.jit(_admit_fn)
 
         # -- unified-step programs (chunk streaming, slot reset, swap) -------
-        def _chunk_body(layers, pos, tokens, slot, start, chunk_len, page_ids,
-                        all_logits=False):
+        def _chunk_body(params, layers, pos, tokens, slot, start, chunk_len,
+                        page_ids, all_logits=False):
             c, template = _slot_surgery_trees()
             slot_layers = jax.tree.map(
                 lambda lay, cap, full, t: (
@@ -250,7 +249,7 @@ class ProgramRegistry:
             if page_ids is not None:
                 states["page_table"] = page_ids[None, :]
             logits, new = lm.chunk_step(
-                self.params, cfg, states, tokens, chunk_len, sctx,
+                params, cfg, states, tokens, chunk_len, sctx,
                 all_logits=all_logits,
             )
             new_layers = self.constrain_layers(
@@ -265,27 +264,31 @@ class ProgramRegistry:
 
         if paged:
 
-            def _chunk_fn(layers, pos, tokens, slot, start, chunk_len, page_ids):
+            def _chunk_fn(params, layers, pos, tokens, slot, start, chunk_len, page_ids):
                 self.chunk_traces += 1
-                return _chunk_body(layers, pos, tokens, slot, start, chunk_len, page_ids)
+                return _chunk_body(
+                    params, layers, pos, tokens, slot, start, chunk_len, page_ids
+                )
 
-            def _verify_fn(layers, pos, tokens, slot, start, chunk_len, page_ids):
+            def _verify_fn(params, layers, pos, tokens, slot, start, chunk_len, page_ids):
                 self.verify_traces += 1
                 return _chunk_body(
-                    layers, pos, tokens, slot, start, chunk_len, page_ids,
+                    params, layers, pos, tokens, slot, start, chunk_len, page_ids,
                     all_logits=True,
                 )
 
         else:
 
-            def _chunk_fn(layers, pos, tokens, slot, start, chunk_len):
+            def _chunk_fn(params, layers, pos, tokens, slot, start, chunk_len):
                 self.chunk_traces += 1
-                return _chunk_body(layers, pos, tokens, slot, start, chunk_len, None)
+                return _chunk_body(
+                    params, layers, pos, tokens, slot, start, chunk_len, None
+                )
 
-            def _verify_fn(layers, pos, tokens, slot, start, chunk_len):
+            def _verify_fn(params, layers, pos, tokens, slot, start, chunk_len):
                 self.verify_traces += 1
                 return _chunk_body(
-                    layers, pos, tokens, slot, start, chunk_len, None,
+                    params, layers, pos, tokens, slot, start, chunk_len, None,
                     all_logits=True,
                 )
 
@@ -355,12 +358,12 @@ class ProgramRegistry:
                     if _leaf_page_axis_sharded(full, sh):
                         return _copy_pages(full, src_ids, dst_ids)
                     spec = sh.spec
-                    return shard_map(
+                    return jax.shard_map(
                         _copy_pages,
                         mesh=sctx.mesh,
                         in_specs=(spec, P(), P()),
                         out_specs=spec,
-                        check=False,
+                        check_vma=False,
                     )(full, src_ids, dst_ids)
 
                 return jax.tree.map(leaf, caps, layers, self._layer_shardings)

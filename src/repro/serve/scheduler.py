@@ -180,12 +180,11 @@ class Scheduler:
 
             self.params = shard_tree(params, lm.model_schema(cfg), sctx)
 
-        # The program registry owns every jitted closure (and the sharded
-        # params reference the chunk body closes over — shard first).
+        # The program registry owns every jitted program.
         self._layouts = blk.stack_layouts(cfg, sched.cache_len, paged=self._paged)
         caps = blk.stack_paged_caps(cfg, sched.cache_len) if self._paged else None
         self.programs = ProgramRegistry(
-            cfg, sctx, self.params,
+            cfg, sctx,
             cache_len=sched.cache_len, layouts=self._layouts, caps=caps,
             layer_shardings=self._layer_shardings,
             page_size=sched.page_size if self._paged else 0, paged=self._paged,

@@ -88,7 +88,7 @@ def verify_slot(s, slot: int, rs: RequestState, draft: np.ndarray) -> bool:
     slot_t = jnp.asarray(slot, jnp.int32)
     start_t = jnp.asarray(start, jnp.int32)
     args = [
-        s._states["layers"], s._states["pos"], toks_dev,
+        s.params, s._states["layers"], s._states["pos"], toks_dev,
         slot_t, start_t, jnp.asarray(n_real, jnp.int32),
     ]
     if s._paged:
@@ -129,7 +129,7 @@ def verify_slot(s, slot: int, rs: RequestState, draft: np.ndarray) -> bool:
             # accepted run from the snapshot through the chunk program
             # (chunk_len is traced — no fresh compile per accept count).
             rargs = [
-                snap, s._states["pos"], toks_dev, slot_t, start_t,
+                s.params, snap, s._states["pos"], toks_dev, slot_t, start_t,
                 jnp.asarray(n_new, jnp.int32),
             ]
             if s._paged:

@@ -2,7 +2,8 @@
 import itertools
 
 import pytest
-from _compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import ConfigMatrix, ConfigMatrixError, HashingError
 from repro.core.hashing import canonicalize, stable_hash, task_key

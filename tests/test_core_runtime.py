@@ -1,5 +1,6 @@
 """Runner, cache, file-queue, task checkpoints, notifications: fault-injection."""
 import os
+import sys
 import threading
 import time
 
@@ -49,12 +50,31 @@ def slow_then_value(ctx: Context):
     return ctx["i"]
 
 
+def worker_state(ctx: Context):
+    """Where the task ran, and whether that process had JAX loaded."""
+    return os.getpid(), "jax" in sys.modules
+
+
 class TestRunner:
     def test_parallel_ok(self):
         r = Runner(square, config=RunnerConfig(max_workers=4, enable_speculation=False))
         results = r.run(_matrix().task_list())
         assert [res.value for res in results] == [i * i for i in range(6)]
         assert all(res.ok for res in results)
+
+    def test_process_mode_spawns_fresh_workers(self):
+        """Process workers start from a fresh import: a fork would carry
+        this process's loaded JAX (and its device) into every worker."""
+        import jax.numpy as jnp
+
+        jnp.zeros(1).block_until_ready()  # this process has started JAX
+        r = Runner(worker_state, config=RunnerConfig(max_workers=2, mode="process"))
+        results = r.run(_matrix(2).task_list())
+        assert all(res.ok for res in results)
+        for res in results:
+            pid, has_jax = res.value
+            assert pid != os.getpid()
+            assert not has_jax
 
     def test_failure_isolation_and_traceback(self):
         def mixed(ctx):

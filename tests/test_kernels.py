@@ -4,7 +4,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.kernels import ops, ref
 from repro.kernels.moe_gmm import pad_group_sizes_to_blocks
@@ -214,3 +215,18 @@ class TestMoEGMM:
         gs = jnp.array([128, 0, 256], jnp.int32)
         gm = pad_group_sizes_to_blocks(gs, 128, 384)
         assert list(np.asarray(gm)) == [0, 2, 2]
+
+
+class TestInterpretMode:
+    @pytest.mark.parametrize(
+        "backend,want", [("cpu", True), ("tpu", False), ("gpu", None)]
+    )
+    def test_default_interpret_by_backend(self, monkeypatch, backend, want):
+        """Interpret on the CPU, compile on a TPU, and refuse anything else
+        rather than hide an accelerator behind the interpreter."""
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        if want is None:
+            with pytest.raises(RuntimeError, match="'gpu'"):
+                ops.default_interpret()
+        else:
+            assert ops.default_interpret() is want

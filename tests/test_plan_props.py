@@ -14,16 +14,12 @@ single program — the point of the layered split. Three families:
     a pure function of the trace — two independent replays produce the
     same decision sequence and the same page-table state.
 """
-import sys
-from pathlib import Path
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
-sys.path.insert(0, str(Path(__file__).parent))
-
-from _compat import given, settings, st  # noqa: E402
-
-from repro.serve import plan as planlib  # noqa: E402
-from repro.serve.memory import MemoryManager  # noqa: E402
-from repro.serve.pages import PageLayout  # noqa: E402
+from repro.serve import plan as planlib
+from repro.serve.memory import MemoryManager
+from repro.serve.pages import PageLayout
 
 
 def _is_pow2(n: int) -> bool:

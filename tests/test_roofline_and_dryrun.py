@@ -188,3 +188,20 @@ class TestSmallMeshLowering:
                 .compile()
             )
         assert compiled is not None
+
+
+class TestCompileCache:
+    def test_fixed_checkout_path_unless_env_set(self, tmp_path, monkeypatch):
+        from repro.launch.compile_cache import enable_compile_cache
+
+        before = jax.config.jax_compilation_cache_dir
+        try:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+            assert enable_compile_cache(tmp_path) == "/elsewhere"
+            assert jax.config.jax_compilation_cache_dir == before
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+            want = str(tmp_path.resolve() / ".jax_cache")
+            assert enable_compile_cache(tmp_path) == want
+            assert jax.config.jax_compilation_cache_dir == want
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)

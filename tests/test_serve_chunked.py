@@ -163,6 +163,35 @@ class TestChunkCompileCounts:
         assert sched.chunk_traces == before, "steady-state workload retraced"
         assert sched.decode_traces == 1
 
+    def test_weights_are_program_arguments(self):
+        """Step programs take the weights as an argument: a jitted closure
+        over them would embed them in the program as constants (gigabytes
+        at full width)."""
+        import jax.numpy as jnp
+
+        cfg, params = _params_for("llama3.2-3b")
+        sched = Scheduler(
+            cfg, params, ShardingCtx.null(),
+            SchedulerConfig(n_slots=2, cache_len=64, chunk_budget=16),
+        )
+        st = sched._states
+        i32 = lambda v: jnp.asarray(v, jnp.int32)
+        span = (
+            st["layers"], st["pos"], jnp.zeros((1, 16), jnp.int32),
+            i32(0), i32(0), i32(16), i32(sched.mem.pt[0, :1]),
+        )
+        traced = {
+            "decode": sched.programs.decode.trace(
+                params, st, jnp.zeros((2, 1), jnp.int32), jnp.ones((2,), bool)
+            ),
+            "chunk": sched.programs.chunk.trace(params, *span),
+            "verify": sched.programs.verify.trace(params, *span),
+        }
+        weight_bytes = sum(x.nbytes for x in jax.tree.leaves(params))
+        for name, t in traced.items():
+            const_bytes = sum(getattr(c, "nbytes", 0) for c in t.jaxpr.consts)
+            assert const_bytes < weight_bytes // 100, (name, const_bytes)
+
     def test_chunk_budget_validation(self):
         cfg, params = _params_for("llama3.2-3b")
         with pytest.raises(ValueError, match="chunk_budget"):

@@ -7,7 +7,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.configs.registry import get_config
 from repro.models import lm

@@ -380,7 +380,9 @@ class TestMeshPlumbing:
 # ==========================================================================
 class TestDecodeRoofline:
     def test_predictor_terms(self):
-        from repro.launch.roofline import HBM_BW, ICI_BW, predict_decode_step
+        from repro.launch.roofline import V5E, device_peaks, predict_decode_step
+
+        peaks = device_peaks(V5E)
 
         cfg = get_config("llama3.2-3b").reduced()
         n = count_params(lm.model_schema(cfg))
@@ -395,13 +397,17 @@ class TestDecodeRoofline:
         assert tp.t_collective > 0
         exp_coll = 2 * cfg.n_layers * (2 * 4 * cfg.d_model * 2 * 0.5)
         assert tp.collective_bytes_per_device == pytest.approx(exp_coll)
-        assert tp.t_memory == pytest.approx((n / HBM_BW))
-        assert tp.t_collective == pytest.approx(exp_coll / ICI_BW)
+        assert tp.t_memory == pytest.approx((n / peaks.hbm_bw))
+        assert tp.t_collective == pytest.approx(exp_coll / peaks.ici_link_bw)
+        assert peaks.ici_link_bw == pytest.approx(50e9)
+        with pytest.raises(KeyError, match="no published peaks"):
+            device_peaks("cpu")
         assert tp.chips == 2
 
     def test_serve_sweep_emits_prediction(self, tmp_path):
         import repro.core as memento
         from repro.experiments import serve_matrix, serve_sweep
+        from repro.launch.roofline import PEAKS
 
         matrix = serve_matrix(
             ["llama3.2-3b"], backends=["xla"], scheduler={"n_slots": [2]},
@@ -418,10 +424,20 @@ class TestDecodeRoofline:
         (r,) = eng.run(matrix)
         assert r.status == "ok"
         v = r.value
-        assert v["predicted_step_ms"] > 0
         assert v["mesh"] == "1x1"
         assert v["mesh_devices"] == 1
-        assert v["predicted_bottleneck"] in ("compute", "memory", "collective")
+        dev = jax.devices()[0]
+        assert v["device"] == {
+            "platform": dev.platform, "kind": dev.device_kind,
+            "count": jax.device_count(),
+        }
+        # A prediction only for a device kind with published peaks.
+        if dev.device_kind in PEAKS:
+            assert v["predicted_step_ms"] > 0
+            assert v["predicted_bottleneck"] in ("compute", "memory", "collective")
+        else:
+            assert v["predicted_step_ms"] is None
+            assert v["predicted_bottleneck"] is None
 
     def test_roofline_ratio_metric(self):
         from repro.analysis.metrics import MetricSpec

@@ -3,29 +3,25 @@ token identity across the model zoo's state families, accept/rollback
 invariants (exact state restoration on rejection, accepted <= drafted, page
 pool conservation), composition with preemption and prefix sharing, bounded
 verify compiles, and stop tokens landing mid-draft."""
-import sys
-from pathlib import Path
-
 import jax
 import numpy as np
 import pytest
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
-sys.path.insert(0, str(Path(__file__).resolve().parent))
-from _compat import given, settings, st  # noqa: E402
-
-from repro.configs.registry import get_config  # noqa: E402
-from repro.models import lm  # noqa: E402
-from repro.models.schema import init_params  # noqa: E402
-from repro.serve.draft import (  # noqa: E402
+from repro.configs.registry import get_config
+from repro.models import lm
+from repro.models.schema import init_params
+from repro.serve.draft import (
     Drafter,
     NgramDrafter,
     ReplayDrafter,
     ScriptDrafter,
 )
-from repro.serve.engine import Engine, ServeConfig  # noqa: E402
-from repro.serve.request import Request  # noqa: E402
-from repro.serve.scheduler import Scheduler, SchedulerConfig  # noqa: E402
-from repro.sharding.rules import ShardingCtx  # noqa: E402
+from repro.serve.engine import Engine, ServeConfig
+from repro.serve.request import Request
+from repro.serve.scheduler import Scheduler, SchedulerConfig
+from repro.sharding.rules import ShardingCtx
 
 
 def _params_for(name):

@@ -1,0 +1,103 @@
+"""The model-reachable Pallas kernels compile for a TPU v5e chip.
+
+Interpret mode (the CPU path of every other kernel test) accepts block
+shapes the TPU compiler refuses, so these tests compile each kernel for a
+described, not attached, v5e chip at llama3.2-3b widths: 24 query heads over
+8 KV heads, head_dim 128. Nothing runs; a compile that the chip's compiler
+would refuse fails here. The topology is described inside a fixture, never
+while the module is imported (only one process at a time may load the TPU
+library).
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import decode_attention as dec
+from repro.kernels import flash_attention as fa
+
+B, H, KV, D = 8, 24, 8, 128
+G = H // KV
+PAGE, POOL_PAGES, MAX_PAGES = 16, 256, 32  # a 512-token table per slot
+CHUNK = 128
+SEQ = 2048
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    cache_was = jax.config.jax_enable_compilation_cache
+    # A compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip.
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _shape(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _pool(one_chip):
+    return _shape(one_chip, (POOL_PAGES + 1, PAGE, KV, D))
+
+
+def test_paged_decode_compiles(one_chip):
+    _compile(
+        lambda q, k, v, pt, pos: dec.paged_decode_attention(
+            q, k, v, pt, pos, n_lp=MAX_PAGES, interpret=False
+        ),
+        _shape(one_chip, (B, KV, G, D)), _pool(one_chip), _pool(one_chip),
+        _shape(one_chip, (B, MAX_PAGES), jnp.int32),
+        _shape(one_chip, (B,), jnp.int32),
+    )
+
+
+def test_paged_chunk_compiles(one_chip):
+    _compile(
+        lambda q, k, v, pt, start: dec.paged_chunk_attention(
+            q, k, v, pt, start, n_lp=MAX_PAGES, group=G, interpret=False
+        ),
+        _shape(one_chip, (1, KV, CHUNK * G, D)), _pool(one_chip), _pool(one_chip),
+        _shape(one_chip, (1, MAX_PAGES), jnp.int32),
+        _shape(one_chip, (1,), jnp.int32),
+    )
+
+
+def _qkv(one_chip):
+    return [_shape(one_chip, (2 * H, SEQ, D)) for _ in range(3)]
+
+
+def test_flash_fwd_compiles(one_chip):
+    _compile(
+        lambda q, k, v: fa.flash_attention_fwd(q, k, v, interpret=False),
+        *_qkv(one_chip),
+    )
+
+
+def test_flash_bwd_compiles(one_chip):
+    q, k, v = _qkv(one_chip)
+    lse = jax.eval_shape(
+        lambda q, k, v: fa.flash_attention_fwd(q, k, v, interpret=True)[1], q, k, v
+    )
+    _compile(
+        lambda q, k, v, o, lse, do: fa.flash_attention_bwd(
+            q, k, v, o, lse, do, interpret=False
+        ),
+        q, k, v, q, _shape(one_chip, lse.shape, lse.dtype), q,
+    )
