@@ -218,11 +218,14 @@ def _paged_chunk_kernel(
         _paged_flush(o_ref, acc_ref, l_ref)
 
 
-def _paged_call(kernel, q, k_pool, v_pool, page_table, vec, *, n_lp, interpret):
+def _paged_call(
+    kernel, q, k_pool, v_pool, page_table, vec, *, n_lp, interpret, name
+):
     """Grid ``(B, n_lp)``: one program per (slot, logical page), all kv
     heads per program, so each physical page is DMA'd once. The page table
     rides as a scalar-prefetch operand and the k/v BlockSpecs fetch physical
-    page ``page_table[b, j]`` at grid step ``(b, j)``."""
+    page ``page_table[b, j]`` at grid step ``(b, j)``. ``name`` names the
+    kernel in compiled programs and profiles."""
     if interpret is None:
         from repro.kernels.ops import default_interpret
 
@@ -248,6 +251,7 @@ def _paged_call(kernel, q, k_pool, v_pool, page_table, vec, *, n_lp, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, R, D), q.dtype),
         interpret=interpret,
+        name=name,
     )(page_table.astype(jnp.int32), vec.astype(jnp.int32), q, k_pool, v_pool)
 
 
@@ -277,7 +281,8 @@ def paged_chunk_attention(
         _paged_chunk_kernel, sm_scale=sm, page=k_pool.shape[1], n_lp=n_lp, G=group
     )
     return _paged_call(
-        kernel, q, k_pool, v_pool, page_table, start, n_lp=n_lp, interpret=interpret
+        kernel, q, k_pool, v_pool, page_table, start, n_lp=n_lp,
+        interpret=interpret, name="paged_chunk_attention",
     )
 
 
@@ -303,5 +308,6 @@ def paged_decode_attention(
         n_lp=n_lp,
     )
     return _paged_call(
-        kernel, q, k_pool, v_pool, page_table, cur_pos, n_lp=n_lp, interpret=interpret
+        kernel, q, k_pool, v_pool, page_table, cur_pos, n_lp=n_lp,
+        interpret=interpret, name="paged_decode_attention",
     )

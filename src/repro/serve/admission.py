@@ -262,21 +262,22 @@ def admit_prefill(s, rs: RequestState) -> bool:
     s._states["pos"] = pos
     s._pos_host[slot] = prompt_len
 
-    now = time.perf_counter()
+    rs.t_admit = time.perf_counter()
     s._key, sub = jax.random.split(s._key)
-    first = int(
-        np.asarray(
-            s.programs.sample(
-                logits[:, -1, :], jnp.full((1,), req.temperature, jnp.float32), sub
-            )
-        )[0]
-    )
+    with s._phase("serve.wait.first_token", rid=rs.rid):
+        first = int(
+            np.asarray(
+                s.programs.sample(
+                    logits[:, -1, :], jnp.full((1,), req.temperature, jnp.float32), sub
+                )
+            )[0]
+        )
+        now = time.perf_counter()  # the first token is on the host
+        rs.prefill_logits = np.asarray(logits[:, -1:, :])
     rs.slot = slot
     rs.prompt_len = prompt_len
     rs.status = RequestStatus.ACTIVE
     rs.tokens = [first]
-    rs.prefill_logits = np.asarray(logits[:, -1:, :])
-    rs.t_admit = now
     rs.t_first_token = now
     rs.t_tokens.append(now)
     s._tokens[slot, 0] = first

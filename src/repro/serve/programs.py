@@ -412,10 +412,16 @@ class ProgramRegistry:
     # -- sharding glue --------------------------------------------------------
     def put(self, x):
         """Host array -> device; fully replicated over the mesh when sharded
-        so every jit program sees one stable input layout per bucket."""
+        so every jit program sees one stable input layout per bucket.
+
+        The transfer gets a private copy: it may read its source after this
+        returns (on the CPU the device array may even alias it), while the
+        executor keeps mutating its host mirrors — page table, next tokens —
+        in place, possibly before the program that reads them has run."""
+        x = np.array(x)
         if self._replicated is None:
             return jnp.asarray(x)
-        return jax.device_put(np.asarray(x), self._replicated)
+        return jax.device_put(x, self._replicated)
 
     def constrain_layers(self, layers):
         """Pin a step program's output layer tree to the profile-resolved

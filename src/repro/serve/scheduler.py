@@ -13,13 +13,16 @@ of replicating it; the **Scheduler** here (with executors in
 admission.py / chunk_exec.py / preempt.py / spec_exec.py) owns request
 lifecycle and loops plan → execute → observe, publishing each step's
 decisions as an immutable `BatchPlan` (`last_plan`) and planner time as
-`plan_time_s`. Semantics are unchanged from the pre-split scheduler and
-pinned by the serve suites: greedy outputs token-identical to
+`plan_time_s`; each host phase of a step is a named span (`_phase`), a
+profiler annotation on the device trace's clock whose wall time adds up
+in `phase_time_s`. Semantics are unchanged from the pre-split scheduler
+and pinned by the serve suites: greedy outputs token-identical to
 `generate_static`, one trace per program bucket, inactive slots decode
 garbage into trash pages behind frozen positions.
 """
 from __future__ import annotations
 
+import contextlib
 import heapq
 import time
 from collections import deque
@@ -243,6 +246,7 @@ class Scheduler:
         self.last_decode_logits: jax.Array | None = None
         self.last_plan: planlib.BatchPlan = planlib.BatchPlan()
         self.plan_time_s = 0.0  # cumulative time inside plan-layer calls
+        self.phase_time_s: dict[str, float] = {}  # cumulative time per step span
         self._ev: dict[str, Any] = {
             "admits": [], "chunk": None, "verifies": [], "rows": (),
             "preempted": [],
@@ -255,6 +259,34 @@ class Scheduler:
         out = fn(*args, **kw)
         self.plan_time_s += time.perf_counter() - t
         return out
+
+    @contextlib.contextmanager
+    def _phase(self, name: str, **meta):
+        """A span of the step: a profiler annotation (recorded only while a
+        trace runs; ``meta`` such as ``rid`` rides in the event's name)
+        whose wall time adds to ``phase_time_s[name]``. The spans are
+        ``serve.step`` around the whole step and, inside it,
+        ``serve.admit``, ``serve.chunk``, ``serve.wait.first_token``,
+        ``serve.verify`` (holding ``serve.wait.verify``), ``serve.pages``,
+        ``serve.decode``, ``serve.wait.decode`` and ``serve.emit``;
+        ``serve.wait.*`` are the blocks on the device."""
+        t = time.perf_counter()
+        try:
+            with jax.profiler.TraceAnnotation(name, **meta):
+                yield
+        finally:
+            self.phase_time_s[name] = (
+                self.phase_time_s.get(name, 0.0) + time.perf_counter() - t
+            )
+
+    @property
+    def host_time_s(self) -> float:
+        """Cumulative host time of the steps: ``serve.step`` less the time
+        it blocked on the device (``serve.wait.*``)."""
+        p = self.phase_time_s
+        return p.get("serve.step", 0.0) - sum(
+            v for k, v in p.items() if k.startswith("serve.wait.")
+        )
 
     def _put(self, x):
         return self.programs.put(x)
@@ -352,22 +384,24 @@ class Scheduler:
         most one prefill chunk, per-slot speculative verifies, then one
         decode step over the remaining rows. The decisions taken are
         published as `last_plan`. Returns True if any program ran."""
-        self._ev = {
-            "admits": [], "chunk": None, "verifies": [], "rows": (),
-            "preempted": [],
-        }
-        try:
-            return self._step()
-        finally:
-            e = self._ev
-            self.last_plan = planlib.BatchPlan(
-                admitted=tuple(e["admits"]), chunk=e["chunk"],
-                verifies=tuple(e["verifies"]), decode_rows=e["rows"],
-                preempted=tuple(e["preempted"]),
-            )
+        with self._phase("serve.step"):
+            self._ev = {
+                "admits": [], "chunk": None, "verifies": [], "rows": (),
+                "preempted": [],
+            }
+            try:
+                return self._step()
+            finally:
+                e = self._ev
+                self.last_plan = planlib.BatchPlan(
+                    admitted=tuple(e["admits"]), chunk=e["chunk"],
+                    verifies=tuple(e["verifies"]), decode_rows=e["rows"],
+                    preempted=tuple(e["preempted"]),
+                )
 
     def _step(self) -> bool:
-        self._admit_pending()
+        with self._phase("serve.admit"):
+            self._admit_pending()
         ran = False
         if self._chunked:
             ran = self._prefill_chunk_step()
@@ -382,42 +416,48 @@ class Scheduler:
         self._ev["rows"] = rows
         if not rows:
             return ran
-        mask = np.zeros_like(self._active_mask)
-        mask[list(rows)] = True
         if self._paged:
-            self._grow_pages(skip=handled)
-            if self._sharing:
-                # CoW guard: decode writes one token per ACTIVE slot at its
-                # current position — fork first if that page is shared (the
-                # scheduler's write pattern keeps this a no-op, but the
-                # invariant is enforced here, not assumed).
-                for slot, rs in list(self._active.items()):
-                    if rs.status is RequestStatus.ACTIVE and slot not in handled:
-                        p = int(self._pos_host[slot])
-                        self._apply_cow(self.mem.prepare_write(slot, p, p + 1))
-            self._states["page_table"] = self._put(self.mem.pt)
+            with self._phase("serve.pages"):
+                self._grow_pages(skip=handled)
+                if self._sharing:
+                    # CoW guard: decode writes one token per ACTIVE slot at
+                    # its current position — fork first if that page is
+                    # shared (the scheduler's write pattern keeps this a
+                    # no-op, but the invariant is enforced here, not
+                    # assumed).
+                    for slot, rs in list(self._active.items()):
+                        if rs.status is RequestStatus.ACTIVE and slot not in handled:
+                            p = int(self._pos_host[slot])
+                            self._apply_cow(self.mem.prepare_write(slot, p, p + 1))
+                self._states["page_table"] = self._put(self.mem.pt)
 
-        self._key, sub = jax.random.split(self._key)
-        logits, self._states = self.programs.decode(
-            self.params, self._states, self._put(self._tokens), self._put(mask)
-        )
-        self.last_decode_logits = logits
-        cols = np.asarray(
-            self.programs.sample(logits[:, -1, :], jnp.asarray(self._temps), sub)
-        )
+        with self._phase("serve.decode"):
+            mask = np.zeros_like(self._active_mask)
+            mask[list(rows)] = True
+            self._key, sub = jax.random.split(self._key)
+            logits, self._states = self.programs.decode(
+                self.params, self._states, self._put(self._tokens), self._put(mask)
+            )
+            self.last_decode_logits = logits
+            cols = self.programs.sample(
+                logits[:, -1, :], jnp.asarray(self._temps), sub
+            )
+        with self._phase("serve.wait.decode"):
+            cols = np.asarray(cols)
         self.total_decode_steps += 1
 
-        now = time.perf_counter()
-        for slot, rs in list(self._active.items()):
-            if rs.status is not RequestStatus.ACTIVE or slot in handled:
-                continue  # still streaming its prompt in, or emitted via spec
-            rs.decode_steps += 1
-            self._pos_host[slot] += 1
-            tok = int(cols[slot])
-            rs.tokens.append(tok)
-            rs.t_tokens.append(now)
-            self._tokens[slot, 0] = tok
-            self._maybe_finish(rs, now)
+        with self._phase("serve.emit"):
+            now = time.perf_counter()
+            for slot, rs in list(self._active.items()):
+                if rs.status is not RequestStatus.ACTIVE or slot in handled:
+                    continue  # still streaming its prompt in, or emitted via spec
+                rs.decode_steps += 1
+                self._pos_host[slot] += 1
+                tok = int(cols[slot])
+                rs.tokens.append(tok)
+                rs.t_tokens.append(now)
+                self._tokens[slot, 0] = tok
+                self._maybe_finish(rs, now)
         return True
 
     # -- chunked prefill (executor in serve/chunk_exec.py) -------------------
@@ -583,6 +623,8 @@ class Scheduler:
             "prefix_hits": self.prefix_hits,
             "prefix_hit_tokens": self.prefix_hit_tokens,
             "plan_time_s": self.plan_time_s,
+            "phase_time_s": dict(self.phase_time_s),
+            "host_time_s": self.host_time_s,
         }
         out["mesh"] = (
             None if self.sctx.mesh is None else dict(self.sctx.mesh.shape)

@@ -35,15 +35,14 @@ def spec_step(s) -> set[int]:
         budget = s._plan(planlib.spec_budget, req.max_new_tokens, len(rs.tokens))
         if budget < 1:
             continue
-        ctx = np.concatenate(
-            [np.asarray(req.prompt, np.int32), np.asarray(rs.tokens, np.int32)]
-        )
-        k = min(s.sched.draft_k, budget)
-        draft = np.asarray(s._drafter.propose(ctx, k), np.int32).reshape(-1)[:k]
-        if draft.size == 0:
-            continue
-        if verify_slot(s, slot, rs, draft):
-            handled.add(slot)
+        with s._phase("serve.verify", rid=rs.rid):
+            ctx = np.concatenate(
+                [np.asarray(req.prompt, np.int32), np.asarray(rs.tokens, np.int32)]
+            )
+            k = min(s.sched.draft_k, budget)
+            draft = np.asarray(s._drafter.propose(ctx, k), np.int32).reshape(-1)[:k]
+            if draft.size and verify_slot(s, slot, rs, draft):
+                handled.add(slot)
     return handled
 
 
@@ -97,7 +96,8 @@ def verify_slot(s, slot: int, rs: RequestState, draft: np.ndarray) -> bool:
     s._ev["verifies"].append(vp)
 
     # Greedy acceptance on host, matching the sample program's cast + argmax.
-    lg = np.asarray(logits[0, :n_real, : s.cfg.vocab_size]).astype(np.float32)
+    with s._phase("serve.wait.verify", rid=rs.rid):
+        lg = np.asarray(logits[0, :n_real, : s.cfg.vocab_size]).astype(np.float32)
     greedy = lg.argmax(axis=-1).astype(np.int32)
     accept = 0
     while accept < k and greedy[accept] == draft[accept]:

@@ -58,7 +58,7 @@ def _pool(one_chip):
 
 
 def test_paged_decode_compiles(one_chip):
-    _compile(
+    compiled = _compile(
         lambda q, k, v, pt, pos: dec.paged_decode_attention(
             q, k, v, pt, pos, n_lp=MAX_PAGES, interpret=False
         ),
@@ -66,10 +66,12 @@ def test_paged_decode_compiles(one_chip):
         _shape(one_chip, (B, MAX_PAGES), jnp.int32),
         _shape(one_chip, (B,), jnp.int32),
     )
+    # the kernel's op carries its own name into the compiled program
+    assert "%paged_decode_attention" in compiled.as_text()
 
 
 def test_paged_chunk_compiles(one_chip):
-    _compile(
+    compiled = _compile(
         lambda q, k, v, pt, start: dec.paged_chunk_attention(
             q, k, v, pt, start, n_lp=MAX_PAGES, group=G, interpret=False
         ),
@@ -77,6 +79,7 @@ def test_paged_chunk_compiles(one_chip):
         _shape(one_chip, (1, MAX_PAGES), jnp.int32),
         _shape(one_chip, (1,), jnp.int32),
     )
+    assert "%paged_chunk_attention" in compiled.as_text()
 
 
 def _qkv(one_chip):
