@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.kernels.decode_attention import live_pages
 from repro.models import blocks as blk
 from repro.models import lm
 from repro.serve import admission, chunk_exec, preempt, spec_exec
@@ -246,6 +247,14 @@ class Scheduler:
         self.last_decode_logits: jax.Array | None = None
         self.last_plan: planlib.BatchPlan = planlib.BatchPlan()
         self.plan_time_s = 0.0  # cumulative time inside plan-layer calls
+        # Pages of one full-width paged layer that the Pallas decode kernel's
+        # walk reads, and the table's pages, summed over slots and decode
+        # steps; None where no such walk runs (the XLA gather reads the table).
+        counted = self._paged and cfg.attn_backend == "pallas"
+        self.decode_pages_walked: int | None = 0 if counted else None
+        self.decode_pages_table: int | None = 0 if counted else None
+        if counted:
+            self._trash_rows = np.array([self.mem.trash_of(s) for s in range(n)])
         self.phase_time_s: dict[str, float] = {}  # cumulative time per step span
         self._ev: dict[str, Any] = {
             "admits": [], "chunk": None, "verifies": [], "rows": (),
@@ -430,6 +439,8 @@ class Scheduler:
                             p = int(self._pos_host[slot])
                             self._apply_cow(self.mem.prepare_write(slot, p, p + 1))
                 self._states["page_table"] = self._put(self.mem.pt)
+                if self.decode_pages_table is not None:
+                    self._count_decode_pages()
 
         with self._phase("serve.decode"):
             mask = np.zeros_like(self._active_mask)
@@ -459,6 +470,18 @@ class Scheduler:
                 self._tokens[slot, 0] = tok
                 self._maybe_finish(rs, now)
         return True
+
+    def _count_decode_pages(self) -> None:
+        """Add this decode step's pages to ``decode_pages_walked`` and
+        ``decode_pages_table``, from the host mirrors of the page table and
+        of the positions the step decodes at (no device read)."""
+        mem = self.mem
+        walked = live_pages(
+            mem.pt[:, 0], self._pos_host, page=mem.page_size,
+            n_lp=mem.max_pages, trash=self._trash_rows, xp=np,
+        )
+        self.decode_pages_walked += int(walked.sum())
+        self.decode_pages_table += mem.pt.size
 
     # -- chunked prefill (executor in serve/chunk_exec.py) -------------------
     def _prefill_chunk_step(self) -> bool:
@@ -625,6 +648,8 @@ class Scheduler:
             "plan_time_s": self.plan_time_s,
             "phase_time_s": dict(self.phase_time_s),
             "host_time_s": self.host_time_s,
+            "decode_pages_walked": self.decode_pages_walked,
+            "decode_pages_table": self.decode_pages_table,
         }
         out["mesh"] = (
             None if self.sctx.mesh is None else dict(self.sctx.mesh.shape)

@@ -92,14 +92,25 @@ class TestOpIdentity:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6)
 
     @needs_2dev
-    def test_data_sharded_kernel_localizes_page_ids(self):
+    @pytest.mark.parametrize("retired", [False, True])
+    def test_data_sharded_kernel_localizes_page_ids(self, retired):
         """(2, 1) mesh over a truly partitioned pool: each shard sees its
         sub-pool with local ids; output equals the global single-device
-        kernel fed the global table."""
+        kernel fed the global table. With ``retired``, the first slot of
+        each shard holds a row of its shard's trash page and a large frozen
+        position: localized, that row starts at the local trash page, so
+        the walk reads nothing for it (a zero row), while the global kernel
+        would read shard 0's trash page as data; the live rows still match."""
         rng = np.random.default_rng(1)
         q, k, v, pt, pos, n_lp = _pool_problem(
             rng, n_slots=4, per_shard=8, shards=2, page=4, KV=2, D=8, H=4
         )
+        live = np.ones(4, bool)
+        if retired:
+            pt, pos = np.array(pt), np.array(pos)
+            for s, trash in ((0, 8), (2, 17)):  # shard sh's trash: 9 * sh + 8
+                pt[s], pos[s], live[s] = trash, 10**6, False
+            pt, pos = jnp.asarray(pt), jnp.asarray(pos)
         ref = kops.paged_decode_attention_op(q, k, v, pt, pos, n_lp=n_lp)
         mesh = mesh_mod.make_test_mesh(data=2, model=1)
         out = kops.paged_decode_attention_sharded(
@@ -109,7 +120,9 @@ class TestOpIdentity:
             table_spec=P("data", None), vec_spec=P("data"),
             localize_pages=True,
         )
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6)
+        out, ref = np.asarray(out), np.asarray(ref)
+        np.testing.assert_allclose(out[live], ref[live], rtol=1e-6)
+        assert not out[~live].any()
 
     @needs_2dev
     def test_chunk_kernel_model_sharded(self):

@@ -3,6 +3,8 @@ paged-vs-static greedy token identity across the model zoo's state
 families, pool-capacity admission backpressure, shape-stable decode under
 page growth, bucketed-prefill compile counts, and the paged decode kernel
 vs its XLA gather reference."""
+from dataclasses import replace
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,6 +13,8 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.configs.registry import get_config
+from repro.kernels import decode_attention as dec
+from repro.kernels import ops as kops
 from repro.models import lm
 from repro.models.schema import init_params
 from repro.serve.cache import graft_pages_leaf
@@ -482,6 +486,36 @@ class TestStatsAndEviction:
         with pytest.raises(KeyError, match="unknown request id"):
             sched.result(99)
 
+    @pytest.mark.parametrize("backend", ["pallas", "xla"])
+    def test_decode_page_counters_match_hand_count(self, backend):
+        """Under the Pallas backend each decode step adds to
+        ``decode_pages_walked`` the pages the paged decode kernel reads (a
+        live slot: up to the page holding its position; a free or retired
+        slot: none) and to ``decode_pages_table`` the table's pages (3 slots
+        x 4). The XLA gather reads the whole table, so it reports None."""
+        cfg, params = _params_for("llama3.2-3b")
+        cfg = replace(cfg, attn_backend=backend)
+        sched = Scheduler(
+            cfg, params, ShardingCtx.null(),
+            SchedulerConfig(n_slots=3, cache_len=64, page_size=16),
+        )
+        a, b, c = _prompts(cfg, [5, 20, 40], seed=3)
+        sched.submit(Request(a, max_new_tokens=6))  # decodes at 5..9
+        sched.submit(Request(b, max_new_tokens=3))  # decodes at 20, 21
+        walked, table = [], []
+        for step in range(6):
+            if step == 2:  # admitted into b's retired slot; decodes at 40..42
+                sched.submit(Request(c, max_new_tokens=4))
+            sched.step()
+            walked.append(sched.stats()["decode_pages_walked"])
+            table.append(sched.stats()["decode_pages_table"])
+        if backend == "xla":
+            assert walked == table == [None] * 6
+            return
+        # a: 1 page a step; b: 2; c: 3; the sixth step decodes nothing.
+        assert np.diff([0] + walked).tolist() == [1 + 2, 1 + 2, 1 + 3, 1 + 3, 1 + 3, 0]
+        assert np.diff([0] + table).tolist() == [12, 12, 12, 12, 12, 0]
+
     def test_result_of_inflight_request(self):
         cfg, params = _params_for("llama3.2-3b")
         sched = Scheduler(
@@ -498,6 +532,28 @@ class TestStatsAndEviction:
 # ==========================================================================
 # Paged graft + paged decode kernel vs reference
 # ==========================================================================
+# The paged decode kernel's cases: page 8 and a 72-page (576-token) table, so
+# dense slots walk 32-page (256-token) blocks. Each case lists its slots' live
+# lengths; None is a retired slot, its table row all trash and its frozen
+# position far past the table. Every case has 5 slots and a 96-page pool, so
+# one compile per window serves them all.
+_BLOCK = 256
+_KERNEL_CASES = {
+    "len-1": [1, 100, None, None, None],
+    "len-page-1": [7, 100, None, None, None],
+    "len-page": [8, 100, None, None, None],
+    "len-page+1": [9, 100, None, None, None],
+    "len-block": [_BLOCK, 100, None, None, None],
+    "len-block+1": [_BLOCK + 1, 100, None, None, None],
+    "len-table": [576, 100, None, None, None],
+    "retired": [None, 37, None, 300, None],
+    "table-wider-than-live": [20, 100, 65, 3, 40],
+}
+_paged_decode_op = jax.jit(
+    kops.paged_decode_attention_op, static_argnames=("n_lp", "window")
+)
+
+
 class TestPagedGraftAndKernel:
     def test_graft_pages_dense_left_align(self):
         P1, page, S = 5, 4, 6
@@ -526,24 +582,30 @@ class TestPagedGraftAndKernel:
             np.testing.assert_array_equal(ring[p % W], np.asarray(src[0, p]))
 
     @pytest.mark.parametrize("window", [0, 13, 16])
-    def test_paged_kernel_matches_gather_reference(self, window):
-        from repro.kernels import ops
-
+    @pytest.mark.parametrize("case", list(_KERNEL_CASES))
+    def test_paged_kernel_matches_gather_reference(self, window, case):
+        """The kernel walks only each slot's live pages, in blocks of
+        pages: every live row equals the XLA gather over the whole table,
+        and a retired row (trash-only table, frozen position far past the
+        table) reads nothing: finite zeros."""
+        lengths = _KERNEL_CASES[case]
         rng = np.random.default_rng(0)
-        B, KV, G, D, page, P, MP = 3, 2, 4, 16, 8, 10, 4
+        B, KV, G, D, page, MP = len(lengths), 2, 4, 16, 8, 72
+        assert dec.pages_per_block(page, KV, D, 4, MP) * page == _BLOCK
+        cap = window or MP * page
+        held = [0 if n is None else -(-min(n, cap) // page) for n in lengths]
+        P = 96  # allocatable pages; row P is the trash page
+        ids = rng.permutation(P)
+        pt = np.full((B, MP), P, np.int32)
+        for b, h in enumerate(held):
+            pt[b, :h], ids = ids[:h], ids[h:]
+        cur = jnp.asarray([10**6 if n is None else n - 1 for n in lengths], jnp.int32)
         kp = jnp.asarray(rng.normal(size=(P + 1, page, KV, D)).astype(np.float32))
         vp = jnp.asarray(rng.normal(size=(P + 1, page, KV, D)).astype(np.float32))
         q = jnp.asarray(rng.normal(size=(B, 1, KV * G, D)).astype(np.float32))
-        pt = np.full((B, MP), P, np.int32)  # trash-padded tables
-        pt[0, :3] = [0, 1, 2]
-        pt[1, :2] = [3, 4]
-        pt[2, :4] = [5, 6, 7, 8]
-        cur = jnp.asarray([17, 9, 30], jnp.int32)
         n_lp = MP if not window else -(-window // page)
 
-        o = ops.paged_decode_attention_op(
-            q, kp, vp, jnp.asarray(pt), cur, n_lp=n_lp, window=window
-        )
+        o = _paged_decode_op(q, kp, vp, jnp.asarray(pt), cur, n_lp=n_lp, window=window)
 
         # XLA reference: materialise the gather, mask by analytic positions.
         T = MP * page
@@ -563,14 +625,15 @@ class TestPagedGraftAndKernel:
             valid = valid & (k_pos > cur[:, None] - window)
         s = jnp.where(valid[:, None, :], s, -1e30)
         ref = jnp.einsum("bht,bthe->bhe", jax.nn.softmax(s, -1), vb).reshape(B, 1, KV * G, D)
-        err = float(jnp.max(jnp.abs(o - ref)))
+        live = np.asarray([n is not None for n in lengths])
+        err = float(jnp.max(jnp.abs(o[live] - ref[live])))
         assert err < 2e-5, err
+        assert np.isfinite(np.asarray(o)).all()
+        assert not np.asarray(o)[~live].any()  # a retired row reads nothing
 
     def test_pallas_backend_end_to_end_paged_decode(self):
         """attn_backend=pallas routes paged decode through the kernel; greedy
         tokens must match the XLA gather path."""
-        from dataclasses import replace
-
         cfg, params = _params_for("llama3.2-3b")
         toks = []
         for backend in ("xla", "pallas"):

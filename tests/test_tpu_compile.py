@@ -3,7 +3,9 @@
 Interpret mode (the CPU path of every other kernel test) accepts block
 shapes the TPU compiler refuses, so these tests compile each kernel for a
 described, not attached, v5e chip at llama3.2-3b widths: 24 query heads over
-8 KV heads, head_dim 128. Nothing runs; a compile that the chip's compiler
+8 KV heads, head_dim 128; the paged decode kernel also at the Qwen3-8B chat
+cell's shapes (32 slots of 4 query heads per KV head, a 160-page table over
+a 1,537-row pool). Nothing runs; a compile that the chip's compiler
 would refuse fails here. The topology is described inside a fixture, never
 while the module is imported (only one process at a time may load the TPU
 library).
@@ -57,14 +59,23 @@ def _pool(one_chip):
     return _shape(one_chip, (POOL_PAGES + 1, PAGE, KV, D))
 
 
-def test_paged_decode_compiles(one_chip):
+@pytest.mark.parametrize(
+    "b, g, max_pages, pool_rows",
+    [
+        (B, G, MAX_PAGES, POOL_PAGES + 1),
+        (32, 4, 160, 1537),  # Qwen3-8B chat: 32 slots, a 2,560-token table
+    ],
+    ids=["llama3.2-3b", "qwen3-8b-chat"],
+)
+def test_paged_decode_compiles(one_chip, b, g, max_pages, pool_rows):
+    pool = _shape(one_chip, (pool_rows, PAGE, KV, D))
     compiled = _compile(
         lambda q, k, v, pt, pos: dec.paged_decode_attention(
-            q, k, v, pt, pos, n_lp=MAX_PAGES, interpret=False
+            q, k, v, pt, pos, n_lp=max_pages, interpret=False
         ),
-        _shape(one_chip, (B, KV, G, D)), _pool(one_chip), _pool(one_chip),
-        _shape(one_chip, (B, MAX_PAGES), jnp.int32),
-        _shape(one_chip, (B,), jnp.int32),
+        _shape(one_chip, (b, KV, g, D)), pool, pool,
+        _shape(one_chip, (b, max_pages), jnp.int32),
+        _shape(one_chip, (b,), jnp.int32),
     )
     # the kernel's op carries its own name into the compiled program
     assert "%paged_decode_attention" in compiled.as_text()
