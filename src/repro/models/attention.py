@@ -615,8 +615,10 @@ def gqa_attention(
                 window=window, prefix_len=prefix_len,
             )
     # Row-parallel o-projection: bf16 output => bf16 TP all-reduce.
-    y = jnp.einsum("bshe,hed->bsd", out, p["wo"].astype(dt), preferred_element_type=dt)
-    return constrain(y.astype(dt), ("batch", "seq", "embed_act"), sctx), new_cache
+    with jax.named_scope("attn.out_proj"):
+        y = jnp.einsum("bshe,hed->bsd", out, p["wo"].astype(dt), preferred_element_type=dt)
+        y = constrain(y.astype(dt), ("batch", "seq", "embed_act"), sctx)
+    return y, new_cache
 
 
 def cross_attention(

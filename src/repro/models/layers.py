@@ -117,8 +117,9 @@ def mlp(p: dict[str, Any], cfg: ModelConfig, x: jax.Array, sctx: ShardingCtx) ->
     # cross-shard partial sum. Emitting it at the compute dtype makes the
     # Megatron all-reduce ride in bf16 (half the ICI bytes of an fp32
     # reduce); the MXU still accumulates fp32 internally per shard.
-    y = jnp.einsum("...f,fd->...d", h, p["down"].astype(dt), preferred_element_type=dt)
-    return constrain(y.astype(dt), ("batch", "seq", "embed_act"), sctx)
+    with jax.named_scope("mlp.down_proj"):
+        y = jnp.einsum("...f,fd->...d", h, p["down"].astype(dt), preferred_element_type=dt)
+        return constrain(y.astype(dt), ("batch", "seq", "embed_act"), sctx)
 
 
 # -- RoPE ------------------------------------------------------------------------
@@ -206,11 +207,13 @@ def chunked_softmax_xent(
 def logits_for_positions(
     x: jax.Array, w_unembed: jax.Array, cfg: ModelConfig, sctx: ShardingCtx
 ) -> jax.Array:
-    """Full logits for small (decode) token counts: (B, Q, V)."""
-    logits = jnp.einsum(
-        "bqd,dv->bqv", x, w_unembed.astype(cdt(cfg)), preferred_element_type=F32
-    )
-    return constrain(logits, ("batch", None, "vocab"), sctx)
+    """Full logits for small (decode) token counts: (B, Q, V), the vocabulary
+    split over ``model`` under tensor parallelism."""
+    with jax.named_scope("head.logits"):
+        logits = jnp.einsum(
+            "bqd,dv->bqv", x, w_unembed.astype(cdt(cfg)), preferred_element_type=F32
+        )
+        return constrain(logits, ("batch", None, "vocab"), sctx)
 
 
 # -- misc -----------------------------------------------------------------------

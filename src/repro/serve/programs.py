@@ -401,11 +401,14 @@ class ProgramRegistry:
             self.swap_in = jax.jit(_swap_in_fn)
 
         def _sample_fn(logits, temps, key):
-            lg = logits[:, : cfg.vocab_size].astype(jnp.float32)
-            greedy = jnp.argmax(lg, axis=-1)
-            scaled = lg / jnp.maximum(temps, 1e-6)[:, None]
-            sampled = jax.random.categorical(key, scaled, axis=-1)
-            return jnp.where(temps > 0.0, sampled, greedy).astype(jnp.int32)
+            # Over a vocabulary split across chips, the choice reduces
+            # across them.
+            with jax.named_scope("head.sample"):
+                lg = logits[:, : cfg.vocab_size].astype(jnp.float32)
+                greedy = jnp.argmax(lg, axis=-1)
+                scaled = lg / jnp.maximum(temps, 1e-6)[:, None]
+                sampled = jax.random.categorical(key, scaled, axis=-1)
+                return jnp.where(temps > 0.0, sampled, greedy).astype(jnp.int32)
 
         self.sample = jax.jit(_sample_fn)
 

@@ -20,7 +20,6 @@ from repro.models.schema import (
     abstract_params,
     init_params,
     is_spec,
-    shard_tree,
     sharding_tree,
 )
 from repro.sharding.rules import ShardingCtx, pspec_for
@@ -49,10 +48,7 @@ def init_decode_state(
     """Real zeroed decode state (smoke tests / serving engine). With a
     meshed ``sctx`` every layer leaf is placed at its profile-resolved
     NamedSharding (heads/kv over model, replicated fallback)."""
-    schema = lm.decode_state_schema(cfg, batch, s_max)
-    state = init_params(schema, jax.random.PRNGKey(0))
-    if sctx is not None and sctx.mesh is not None:
-        state["layers"] = shard_tree(state["layers"], schema["layers"], sctx)
+    state = _materialise(lm.decode_state_schema(cfg, batch, s_max), sctx)
     state["pos"] = jnp.asarray(start_pos, jnp.int32)
     return state
 
@@ -69,12 +65,21 @@ def init_paged_decode_state(
     With a meshed ``sctx`` the pool leaves shard on kv_heads/head_dim over
     ``model`` (page axes replicated): every device owns its slice of every
     page, so page-table indirection stays a device-local gather."""
-    schema = lm.decode_state_schema(cfg, batch, s_max, pages=pages)
-    state = init_params(schema, jax.random.PRNGKey(0))
-    if sctx is not None and sctx.mesh is not None:
-        state["layers"] = shard_tree(state["layers"], schema["layers"], sctx)
+    state = _materialise(lm.decode_state_schema(cfg, batch, s_max, pages=pages), sctx)
     state["pos"] = jnp.zeros((batch,), jnp.int32)
     return state
+
+
+def _materialise(schema: dict[str, Any], sctx: ShardingCtx | None) -> dict[str, Any]:
+    """A decode-state schema as arrays. With a mesh each layer leaf is made
+    in place at its resolved sharding, so no device ever holds a whole leaf:
+    at full width one KV pool of every head is more than a chip holds."""
+    key = jax.random.PRNGKey(0)
+    if sctx is None or sctx.mesh is None:
+        return init_params(schema, key)
+    shardings = {k: sctx.replicated() for k in schema}
+    shardings["layers"] = sharding_tree(schema["layers"], sctx)
+    return jax.jit(lambda k: init_params(schema, k), out_shardings=shardings)(key)
 
 
 def decode_state_shardings(

@@ -365,6 +365,30 @@ class TestMeshPlumbing:
         assert sched.stats()["mesh"] is None
         assert sched.stats()["mesh_devices"] == 1
 
+    def test_paged_state_is_made_at_its_shardings(self, monkeypatch):
+        """The page pool is made in place at its sharding: a pool of every
+        KV head never lands whole on one device first (at full width it
+        does not fit one chip beside that chip's share of the weights)."""
+        from repro.serve.pages import PageLayout, model_page_span
+        from repro.serve.step import decode_state_shardings, init_paged_decode_state
+        from repro.sharding.rules import get_profile
+
+        cfg = get_config("llama3.2-3b").reduced()
+        n = 2 if mesh_mod.devices_required(2) else 1
+        sctx = ShardingCtx(mesh_mod.make_test_mesh(1, n), get_profile("decode_default"))
+        layout = PageLayout(page_size=16, n_pages=8, span=model_page_span(cfg, 64))
+
+        def no_put(*a, **kw):
+            raise AssertionError("a whole state leaf was put on a device")
+
+        monkeypatch.setattr(jax, "device_put", no_put)
+        state = init_paged_decode_state(cfg, 2, 64, layout, sctx=sctx)
+        monkeypatch.undo()
+        want = decode_state_shardings(cfg, 2, 64, sctx, pages=layout)
+        for leaf, sh in zip(jax.tree.leaves(state["layers"]), jax.tree.leaves(want)):
+            assert leaf.sharding == sh
+            assert not np.asarray(leaf).any()
+
     def test_serve_sweep_mesh_shape_knob_normalizes(self):
         from repro.experiments.serve import _mesh_shape_opt
 
