@@ -14,8 +14,12 @@ same kernel serves dense caches and ring buffers.
 
 ``paged_decode_attention`` is the paged-serving variant: KV lives in a
 shared page pool (P+1, page, KV, D), row P the trash page, and slot b's
-logical page j is physical page ``page_table[b, j]``. Its cost follows each
-slot's live context, not the table width. The grid is one step per slot;
+logical page j is physical page ``page_table[b, j]``. The pool may also be
+the whole layer stack's, (L, P+1, page, KV, D), read at a ``layer`` index
+that rides in as a scalar-prefetch operand: the model's layer scan carries
+the stacked pool and updates it in place, and no layer's pool is ever
+sliced out of it. Its cost follows each slot's live context, not the
+table width. The grid is one step per slot;
 the pools stay in HBM and the kernel copies pages itself. A slot reads its
 live pages only — up to the page holding ``cur_pos[b]``, capped at ``n_lp``
 (the table width for dense layers, ``ceil(window / page)`` for ring-folded
@@ -177,8 +181,18 @@ def _decode_k_pos(s_idx, cur, window):
     return jnp.where(s_idx < window, k_pos, -1)
 
 
+def _stacked_pools(k_pool, v_pool, layer):
+    """The pools as a layer stack (L, P+1, page, KV, D) and the layer read,
+    as a (1,) int32 scalar-prefetch operand. A single layer's pool
+    (``layer`` None) is a stack of one."""
+    if layer is None:
+        return k_pool[None], v_pool[None], jnp.zeros((1,), jnp.int32)
+    return k_pool, v_pool, jnp.reshape(layer, (1,)).astype(jnp.int32)
+
+
 def _paged_decode_kernel(
-    pt_ref, cp_ref,  # scalar prefetch: (B, MP) page table, (B,) cur positions
+    # scalar prefetch: (B, MP) page table, (B,) cur positions, (1,) layer
+    pt_ref, cp_ref, ly_ref,
     q_ref, k_hbm, v_hbm, o_ref,
     k_buf, v_buf, sem, acc_ref, m_ref, l_ref,
     *, sm_scale, window, page, n_lp, ppb,
@@ -189,8 +203,9 @@ def _paged_decode_kernel(
     HBM) into one of two VMEM buffers while the block in the other
     computes."""
     b = pl.program_id(0)
+    layer = ly_ref[0]
     n_live = live_pages(
-        pt_ref[b, 0], cp_ref[b], page=page, n_lp=n_lp, trash=k_hbm.shape[0] - 1
+        pt_ref[b, 0], cp_ref[b], page=page, n_lp=n_lp, trash=k_hbm.shape[1] - 1
     )
     n_blk = (n_live + ppb - 1) // ppb
 
@@ -205,7 +220,8 @@ def _paged_decode_kernel(
                 pid = pt_ref[b, j]
                 for src, dst, t in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
                     cp = pltpu.make_async_copy(
-                        src.at[pid], dst.at[buf, pl.ds(c * page, page)], sem.at[t, buf]
+                        src.at[layer, pid], dst.at[buf, pl.ds(c * page, page)],
+                        sem.at[t, buf],
                     )
                     cp.start() if start else cp.wait()
 
@@ -246,7 +262,8 @@ def _paged_decode_kernel(
 
 
 def _paged_chunk_kernel(
-    pt_ref, start_ref,  # scalar prefetch: (B, MP) page table, (B,) chunk starts
+    # scalar prefetch: (B, MP) page table, (B,) chunk starts, (1,) layer
+    pt_ref, start_ref, ly_ref,
     q_ref, k_ref, v_ref, o_ref,
     acc_ref, m_ref, l_ref,
     *, sm_scale, page, n_lp, G,
@@ -282,10 +299,11 @@ def _paged_chunk_kernel(
 
 def paged_chunk_attention(
     q: jax.Array,  # (B, KV, C*G, D) chunk queries, row r = c*G + g
-    k_pool: jax.Array,  # (P+1, page, KV, D) shared pool incl. trash page
+    k_pool: jax.Array,  # (P+1, page, KV, D) incl. trash page, or (L, P+1, ...)
     v_pool: jax.Array,
     page_table: jax.Array,  # (B, max_pages) int32
     start: jax.Array,  # (B,) int32: tokens cached before the chunk
+    layer: jax.Array | None = None,  # int32 scalar: the stacked pools' layer
     *, n_lp: int, group: int, sm_scale: float | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
@@ -307,16 +325,21 @@ def paged_chunk_attention(
     assert R % group == 0, (q.shape, group)
     assert n_lp <= page_table.shape[1], (n_lp, page_table.shape)
     sm = sm_scale if sm_scale is not None else D ** -0.5
-    page = k_pool.shape[1]
+    k_pool, v_pool, layer = _stacked_pools(k_pool, v_pool, layer)
+    page = k_pool.shape[2]
     kernel = functools.partial(
         _paged_chunk_kernel, sm_scale=sm, page=page, n_lp=n_lp, G=group
     )
     # Grid (B, n_lp): one program per (slot, logical page), all kv heads per
-    # program; the k/v BlockSpecs fetch physical page page_table[b, j].
-    kv_spec = pl.BlockSpec((1, page, KV, D), lambda b, j, pt, st: (pt[b, j], 0, 0, 0))
-    q_spec = pl.BlockSpec((1, KV, R, D), lambda b, j, pt, st: (b, 0, 0, 0))
+    # program; the k/v BlockSpecs fetch physical page page_table[b, j] of
+    # the layer read.
+    kv_spec = pl.BlockSpec(
+        (pl.squeezed, 1, page, KV, D),
+        lambda b, j, pt, st, ly: (ly[0], pt[b, j], 0, 0, 0),
+    )
+    q_spec = pl.BlockSpec((1, KV, R, D), lambda b, j, pt, st, ly: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, n_lp),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=q_spec,
@@ -328,7 +351,7 @@ def paged_chunk_attention(
         out_shape=jax.ShapeDtypeStruct((B, KV, R, D), q.dtype),
         interpret=interpret,
         name="paged_chunk_attention",
-    )(page_table.astype(jnp.int32), start.astype(jnp.int32), q, k_pool, v_pool)
+    )(page_table.astype(jnp.int32), start.astype(jnp.int32), layer, q, k_pool, v_pool)
 
 
 def _row_state(KV, R, D):
@@ -361,10 +384,11 @@ def pages_per_block(page: int, kv: int, d: int, itemsize: int, n_lp: int) -> int
 
 def paged_decode_attention(
     q: jax.Array,  # (B, KV, G, D)
-    k_pool: jax.Array,  # (P+1, page, KV, D) shared pool incl. trash page
+    k_pool: jax.Array,  # (P+1, page, KV, D) incl. trash page, or (L, P+1, ...)
     v_pool: jax.Array,
     page_table: jax.Array,  # (B, max_pages) int32 physical page per logical page
     cur_pos: jax.Array,  # (B,) int32 position of each slot's query token
+    layer: jax.Array | None = None,  # int32 scalar: the stacked pools' layer
     *, n_lp: int, window: int = 0, sm_scale: float | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
@@ -380,7 +404,8 @@ def paged_decode_attention(
 
         interpret = default_interpret()
     B, KV, G, D = q.shape
-    page = k_pool.shape[1]
+    k_pool, v_pool, layer = _stacked_pools(k_pool, v_pool, layer)
+    page = k_pool.shape[2]
     assert n_lp <= page_table.shape[1], (n_lp, page_table.shape)
     ppb = pages_per_block(page, KV, D, k_pool.dtype.itemsize, n_lp)
     sm = sm_scale if sm_scale is not None else D ** -0.5
@@ -388,11 +413,11 @@ def paged_decode_attention(
         _paged_decode_kernel, sm_scale=sm, window=window, page=page, n_lp=n_lp,
         ppb=ppb,
     )
-    q_spec = pl.BlockSpec((1, KV, G, D), lambda b, pt, cp: (b, 0, 0, 0))
+    q_spec = pl.BlockSpec((1, KV, G, D), lambda b, pt, cp, ly: (b, 0, 0, 0))
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
     kv_buf = pltpu.VMEM((2, ppb * page, KV, D), k_pool.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B,),
         in_specs=[q_spec, pool_spec, pool_spec],
         out_specs=q_spec,
@@ -410,4 +435,4 @@ def paged_decode_attention(
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_decode_attention",
-    )(page_table.astype(jnp.int32), cur_pos.astype(jnp.int32), q, k_pool, v_pool)
+    )(page_table.astype(jnp.int32), cur_pos.astype(jnp.int32), layer, q, k_pool, v_pool)

@@ -16,6 +16,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from . import decode_attention as _dec
 from . import flash_attention as _fa
@@ -112,19 +113,20 @@ def decode_attention_op(
 
 def paged_decode_attention_op(
     q: jax.Array,  # (B, 1, H, D)
-    k_pool: jax.Array,  # (P+1, page, KV, D) shared page pool
+    k_pool: jax.Array,  # (P+1, page, KV, D) shared page pool, or (L, P+1, ...) stacked
     v_pool: jax.Array,
     page_table: jax.Array,  # (B, max_pages) int32
     cur_pos: jax.Array,  # (B,) int32
+    layer: jax.Array | None = None,  # int32 scalar: the stacked pools' layer
     *, n_lp: int, window: int = 0,
 ) -> jax.Array:
     """Model-layout paged decode: KV blocks gathered via the page table."""
     B, _, H, D = q.shape
-    KV = k_pool.shape[2]
+    KV = k_pool.shape[-2]
     G = H // KV
     qf = q.reshape(B, H, D).reshape(B, KV, G, D)
     o = _dec.paged_decode_attention(
-        qf, k_pool, v_pool, page_table, cur_pos, n_lp=n_lp, window=window,
+        qf, k_pool, v_pool, page_table, cur_pos, layer, n_lp=n_lp, window=window,
         interpret=default_interpret(),
     )
     return o.reshape(B, 1, H, D)
@@ -132,21 +134,22 @@ def paged_decode_attention_op(
 
 def paged_chunk_attention_op(
     q: jax.Array,  # (B, C, H, D) chunk queries
-    k_pool: jax.Array,  # (P+1, page, KV, D) shared page pool (chunk K/V written)
+    k_pool: jax.Array,  # (P+1, page, KV, D) shared page pool (chunk K/V written), or stacked
     v_pool: jax.Array,
     page_table: jax.Array,  # (B, max_pages) int32
     start: jax.Array,  # (B,) int32: tokens cached before the chunk
+    layer: jax.Array | None = None,  # int32 scalar: the stacked pools' layer
     *, n_lp: int,
 ) -> jax.Array:
     """Model-layout chunked-prefill attention over the paged KV (dense
     layers). The chunk's own K/V must already be scattered into the pool."""
     B, C, H, D = q.shape
-    KV = k_pool.shape[2]
+    KV = k_pool.shape[-2]
     G = H // KV
     # Row order c*G + g per kv-head: (B, C, KV, G, D) -> (B, KV, C*G, D).
     qf = q.reshape(B, C, KV, G, D).transpose(0, 2, 1, 3, 4).reshape(B, KV, C * G, D)
     o = _dec.paged_chunk_attention(
-        qf, k_pool, v_pool, page_table, start, n_lp=n_lp, group=G,
+        qf, k_pool, v_pool, page_table, start, layer, n_lp=n_lp, group=G,
         interpret=default_interpret(),
     )
     return o.reshape(B, KV, C, G, D).transpose(0, 2, 1, 3, 4).reshape(B, C, H, D)
@@ -159,13 +162,36 @@ def paged_chunk_attention_op(
 # paged kernels run inside shard_map: each shard calls the single-device op
 # on its local q rows / head slice / pool slice. The caller (the attention
 # layer) resolves the PartitionSpecs from the actual operand shapes and
-# mesh; ``localize_pages`` is set only when the pool is truly partitioned
-# across data shards (host page ids are then global — shard d owns rows
-# [d * rows_local, (d + 1) * rows_local) of the pool, each block ending in
-# its own trash row — so the local table is ``global - d * rows_local``).
+# mesh; ``pool_spec`` covers the pools as passed, with a leading layer
+# entry for stacked pools, whose ``layer`` scalar every shard reads
+# replicated. ``localize_pages`` is set only when the pool is truly
+# partitioned across data shards (host page ids are then global — shard d
+# owns rows [d * rows_local, (d + 1) * rows_local) of the pool, each block
+# ending in its own trash row — so the local table is
+# ``global - d * rows_local``).
 def _localized(page_table: jax.Array, pool_rows_local: int) -> jax.Array:
     d = jax.lax.axis_index("data").astype(jnp.int32)
     return page_table - d * pool_rows_local
+
+
+def _shard_paged(op, q, k_pool, v_pool, page_table, vec, layer, *, mesh,
+                 q_spec, pool_spec, table_spec, vec_spec, localize_pages):
+    """Run a paged op per shard: pool rows ``shape[-4]`` are what a data
+    shard owns when ``localize_pages``."""
+    rows_local = k_pool.shape[-4] // (mesh.shape["data"] if localize_pages else 1)
+
+    def body(qs, ks, vs, pt, v, *ly):
+        if localize_pages:
+            pt = _localized(pt, rows_local)
+        return op(qs, ks, vs, pt, v, *ly)
+
+    args = (q, k_pool, v_pool, page_table, vec)
+    specs = (q_spec, pool_spec, pool_spec, table_spec, vec_spec)
+    if layer is not None:
+        args, specs = args + (layer,), specs + (P(),)
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=specs, out_specs=q_spec, check_vma=False,
+    )(*args)
 
 
 def paged_decode_attention_sharded(
@@ -174,6 +200,7 @@ def paged_decode_attention_sharded(
     v_pool: jax.Array,
     page_table: jax.Array,
     cur_pos: jax.Array,
+    layer: jax.Array | None = None,
     *,
     n_lp: int,
     window: int = 0,
@@ -185,24 +212,12 @@ def paged_decode_attention_sharded(
     localize_pages: bool = False,
 ) -> jax.Array:
     """``paged_decode_attention_op`` run per-shard under ``mesh``."""
-    rows_local = k_pool.shape[0] // (
-        mesh.shape["data"] if localize_pages else 1
+    return _shard_paged(
+        functools.partial(paged_decode_attention_op, n_lp=n_lp, window=window),
+        q, k_pool, v_pool, page_table, cur_pos, layer, mesh=mesh,
+        q_spec=q_spec, pool_spec=pool_spec, table_spec=table_spec,
+        vec_spec=vec_spec, localize_pages=localize_pages,
     )
-
-    def body(qs, ks, vs, pt, pos):
-        if localize_pages:
-            pt = _localized(pt, rows_local)
-        return paged_decode_attention_op(
-            qs, ks, vs, pt, pos, n_lp=n_lp, window=window
-        )
-
-    return jax.shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(q_spec, pool_spec, pool_spec, table_spec, vec_spec),
-        out_specs=q_spec,
-        check_vma=False,
-    )(q, k_pool, v_pool, page_table, cur_pos)
 
 
 def paged_chunk_attention_sharded(
@@ -211,6 +226,7 @@ def paged_chunk_attention_sharded(
     v_pool: jax.Array,
     page_table: jax.Array,
     start: jax.Array,
+    layer: jax.Array | None = None,
     *,
     n_lp: int,
     mesh,
@@ -223,16 +239,12 @@ def paged_chunk_attention_sharded(
     are single-slot (B == 1), so only the head/model axis partitions —
     the caller falls back to the XLA gather path when the pool is
     data-partitioned."""
-    def body(qs, ks, vs, pt, st):
-        return paged_chunk_attention_op(qs, ks, vs, pt, st, n_lp=n_lp)
-
-    return jax.shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(q_spec, pool_spec, pool_spec, table_spec, vec_spec),
-        out_specs=q_spec,
-        check_vma=False,
-    )(q, k_pool, v_pool, page_table, start)
+    return _shard_paged(
+        functools.partial(paged_chunk_attention_op, n_lp=n_lp),
+        q, k_pool, v_pool, page_table, start, layer, mesh=mesh,
+        q_spec=q_spec, pool_spec=pool_spec, table_spec=table_spec,
+        vec_spec=vec_spec, localize_pages=False,
+    )
 
 
 # ==========================================================================

@@ -56,6 +56,64 @@ def decode_attention_ref(
     return jnp.einsum("bgt,btd->bgd", p, v.astype(F32)).astype(q.dtype)
 
 
+def _paged_gather(pool: jax.Array, page_table: jax.Array, n_lp: int) -> jax.Array:
+    """(B, n_lp * page, KV, D): each slot's first ``n_lp`` table pages."""
+    B = page_table.shape[0]
+    g = pool[page_table[:, :n_lp]]  # (B, n_lp, page, KV, D)
+    return g.reshape(B, -1, *pool.shape[2:]).astype(F32)
+
+
+def paged_decode_attention_ref(
+    q: jax.Array,  # (B, KV, G, D)
+    k_pool: jax.Array,  # (P+1, page, KV, D)
+    v_pool: jax.Array,
+    page_table: jax.Array,  # (B, max_pages)
+    cur_pos: jax.Array,  # (B,)
+    *, n_lp: int, window: int = 0, sm_scale: float | None = None,
+) -> jax.Array:
+    """One query token per slot against its table's first ``n_lp`` pages;
+    logical slot s holds position s, or in a ring of ``window`` the latest
+    position p <= cur_pos with p % window == s."""
+    D = q.shape[-1]
+    sm = sm_scale if sm_scale is not None else D ** -0.5
+    k = _paged_gather(k_pool, page_table, n_lp)
+    v = _paged_gather(v_pool, page_table, n_lp)
+    idx = jnp.arange(k.shape[1])[None, :]
+    cur = cur_pos[:, None]
+    k_pos = idx
+    if window:
+        k_pos = jnp.where(idx < window, cur - ((cur - idx) % window), -1)
+    valid = (k_pos <= cur) & (k_pos >= 0)
+    if window:
+        valid = valid & (k_pos > cur - window)
+    s = jnp.einsum("bkgd,btkd->bkgt", q.astype(F32), k) * sm
+    s = jnp.where(valid[:, None, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bkgt,btkd->bkgd", p, v).astype(q.dtype)
+
+
+def paged_chunk_attention_ref(
+    q: jax.Array,  # (B, KV, C*G, D), row r = c*G + g
+    k_pool: jax.Array,  # (P+1, page, KV, D)
+    v_pool: jax.Array,
+    page_table: jax.Array,  # (B, max_pages)
+    start: jax.Array,  # (B,) tokens cached before the chunk
+    *, n_lp: int, group: int, sm_scale: float | None = None,
+) -> jax.Array:
+    """Chunk token c of slot b, at position start[b] + c, against logical
+    slots 0 .. start[b] + c of its table's first ``n_lp`` pages."""
+    D = q.shape[-1]
+    sm = sm_scale if sm_scale is not None else D ** -0.5
+    k = _paged_gather(k_pool, page_table, n_lp)
+    v = _paged_gather(v_pool, page_table, n_lp)
+    q_pos = start[:, None] + jnp.arange(q.shape[2])[None, :] // group  # (B, R)
+    valid = jnp.arange(k.shape[1])[None, None, :] <= q_pos[:, :, None]  # (B, R, T)
+    s = jnp.einsum("bkrd,btkd->bkrt", q.astype(F32), k) * sm
+    s = jnp.where(valid[:, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bkrt,btkd->bkrd", p, v).astype(q.dtype)
+
+
 # -- RG-LRU ---------------------------------------------------------------
 def rglru_ref(a: jax.Array, b: jax.Array, h0: jax.Array | None = None) -> jax.Array:
     """Sequential h_t = a_t h_{t-1} + b_t. a/b: (B, T, D)."""

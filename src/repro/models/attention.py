@@ -23,6 +23,8 @@ from repro.models.schema import ParamSpec
 from repro.sharding.rules import ShardingCtx, constrain
 
 NEG_INF = -1e30
+# Logical axes of a stacked page pool: (layer, pool rows, page, kv, hd).
+_POOL_AXES = ("layer", "pages", None, "kv_heads", "head_dim")
 
 
 def _pallas_ok(sctx: ShardingCtx) -> bool:
@@ -40,7 +42,8 @@ def _paged_kernel_specs(
 ):
     """PartitionSpecs to run a paged Pallas kernel per-shard under the
     current mesh, or None when the operands don't partition cleanly (the
-    XLA gather path handles those layouts through GSPMD).
+    XLA gather path handles those layouts through GSPMD). The pools are
+    the layer stack's, read at a replicated layer index.
 
     The head axis splits over ``model`` when it divides both q and KV
     heads. The batch axis (decode only: ``batch_sharded``) splits over
@@ -71,7 +74,7 @@ def _paged_kernel_specs(
     return {
         "mesh": sctx.mesh,
         "q_spec": P(d, None, m, None),
-        "pool_spec": P(pages, None, m, None),
+        "pool_spec": P(None, pages, None, m, None),  # (layer, rows, page, KV, D)
         "table_spec": P(d, None),
         "vec_spec": P(d),
         "localize_pages": localize,
@@ -127,7 +130,9 @@ def attention_schema(cfg: ModelConfig, cross: bool = False) -> dict[str, Any]:
 # ==========================================================================
 class KVCache(NamedTuple):
     """Dense GQA cache. ``k``/``v``: (B, S_max, n_kv, hd). For windowed layers
-    S_max == window and writes wrap (ring buffer)."""
+    S_max == window and writes wrap (ring buffer). Paged, ``k``/``v`` are the
+    whole layer stack's page pools, (L, P+1, page, n_kv, hd), read and
+    written at the block's layer index."""
 
     k: jax.Array
     v: jax.Array
@@ -319,22 +324,25 @@ def _chunk_attend(
     chunk_len: jax.Array,  # scalar: number of real tokens in the chunk
     window: int,
     page_table: jax.Array | None,  # (1, max_pages) when the leaf is paged
+    layer: jax.Array | None,  # the stacked pools' layer when the leaf is paged
 ) -> tuple[jax.Array, KVCache]:
     dt = cdt(cfg)
     B, C = q.shape[0], q.shape[1]
     q_pos_b = jnp.broadcast_to(qpos[None, :], (B, C))
 
     if page_table is not None:
-        page = cache.k.shape[1]
+        # The pools are the whole stack's (L, P+1, page, KV, D): this layer
+        # reads and writes them at ``layer`` and nothing is sliced out.
+        page = cache.k.shape[2]
         max_pages = page_table.shape[1]
-        trash = cache.k.shape[0] - 1
+        trash = cache.k.shape[1] - 1
         if window:
             n_lp = min(-(-window // page), max_pages)
             # Read the pre-write ring plus the chunk keys side by side.
             sel = page_table[:, :n_lp]
             T = n_lp * page
-            kold = cache.k[sel].reshape(B, T, *cache.k.shape[2:]).astype(dt)
-            vold = cache.v[sel].reshape(B, T, *cache.v.shape[2:]).astype(dt)
+            kold = cache.k[layer, sel].reshape(B, T, *cache.k.shape[3:]).astype(dt)
+            vold = cache.v[layer, sel].reshape(B, T, *cache.v.shape[3:]).astype(dt)
             k_pos_old = _ring_positions(T, window, start - 1)
             k_pos_c = jnp.where(valid_tok, qpos, -1)
             kk = jnp.concatenate([kold, k.astype(dt)], axis=1)
@@ -348,8 +356,8 @@ def _chunk_attend(
             lslot = qpos % window
             pid = jnp.where(keep, page_table[0, lslot // page], trash)
             off = lslot % page
-            ck = cache.k.at[pid, off].set(k[0].astype(cache.k.dtype))
-            cv = cache.v.at[pid, off].set(v[0].astype(cache.v.dtype))
+            ck = cache.k.at[layer, pid, off].set(k[0].astype(cache.k.dtype))
+            cv = cache.v.at[layer, pid, off].set(v[0].astype(cache.v.dtype))
         else:
             # Dense: scatter the chunk into its pages first (pads -> trash),
             # then attend over the whole table — stale or trash-backed slots
@@ -364,21 +372,21 @@ def _chunk_attend(
             # exclusively owned (refcount 1) by the time the program runs.
             pid = jnp.where(valid_tok, page_table[0, qpos // page], trash)
             off = qpos % page
-            ck = cache.k.at[pid, off].set(k[0].astype(cache.k.dtype))
-            cv = cache.v.at[pid, off].set(v[0].astype(cache.v.dtype))
+            ck = cache.k.at[layer, pid, off].set(k[0].astype(cache.k.dtype))
+            cv = cache.v.at[layer, pid, off].set(v[0].astype(cache.v.dtype))
             specs = None
             if cfg.attn_backend == "pallas" and not _pallas_ok(sctx):
                 # Chunks are single-slot (B == 1): only the head axis can
                 # partition, so a data-partitioned pool falls back to XLA.
                 specs = _paged_kernel_specs(
-                    sctx, B=B, H=q.shape[2], KV=ck.shape[2],
-                    total_pages=ck.shape[0], batch_sharded=False,
+                    sctx, B=B, H=q.shape[2], KV=ck.shape[3],
+                    total_pages=ck.shape[1], batch_sharded=False,
                 )
             if cfg.attn_backend == "pallas" and _pallas_ok(sctx):
                 from repro.kernels import ops as _kops
 
                 out = _kops.paged_chunk_attention_op(
-                    q, ck, cv, page_table, jnp.broadcast_to(start, (B,)),
+                    q, ck, cv, page_table, jnp.broadcast_to(start, (B,)), layer,
                     n_lp=max_pages,
                 ).astype(dt)
             elif specs is not None:
@@ -386,20 +394,20 @@ def _chunk_attend(
 
                 specs.pop("localize_pages")
                 out = _kops.paged_chunk_attention_sharded(
-                    q, ck, cv, page_table, jnp.broadcast_to(start, (B,)),
+                    q, ck, cv, page_table, jnp.broadcast_to(start, (B,)), layer,
                     n_lp=max_pages, **specs,
                 ).astype(dt)
             else:
                 sel = page_table  # (B, max_pages)
                 T = max_pages * page
-                kg = ck[sel].reshape(B, T, *ck.shape[2:]).astype(dt)
-                vg = cv[sel].reshape(B, T, *cv.shape[2:]).astype(dt)
+                kg = ck[layer, sel].reshape(B, T, *ck.shape[3:]).astype(dt)
+                vg = cv[layer, sel].reshape(B, T, *cv.shape[3:]).astype(dt)
                 k_pos = jnp.broadcast_to(
                     jnp.arange(T, dtype=jnp.int32)[None, :], (B, T)
                 )
                 out = _sdpa_span(q, kg, vg, k_pos, q_pos_b, cfg)
-        ck = constrain(ck, ("pages", None, "kv_heads", "head_dim"), sctx)
-        cv = constrain(cv, ("pages", None, "kv_heads", "head_dim"), sctx)
+        ck = constrain(ck, _POOL_AXES, sctx)
+        cv = constrain(cv, _POOL_AXES, sctx)
         return out, KVCache(ck, cv)
 
     # Contiguous per-slot row.
@@ -471,6 +479,7 @@ def gqa_attention(
     use_rope: bool = True,
     page_table: jax.Array | None = None,  # (B, max_pages) int32, paged decode only
     chunk_len: jax.Array | None = None,  # valid tokens in a chunk (chunk mode)
+    layer: jax.Array | None = None,  # int32 scalar: the stacked pools' layer (paged)
     sctx: ShardingCtx,
 ) -> tuple[jax.Array, KVCache | None]:
     dt = cdt(cfg)
@@ -508,13 +517,13 @@ def gqa_attention(
         out, new_cache = _chunk_attend(
             q, k, v, cache, cfg, sctx,
             qpos=qpos, valid_tok=valid_tok, start=start, chunk_len=chunk_len,
-            window=window, page_table=page_table,
+            window=window, page_table=page_table, layer=layer,
         )
     elif mode == "decode" and page_table is not None:
         assert cache is not None and cur_pos is not None
-        # Paged decode: the cache is a shared page pool (P+1, page, kv, hd)
-        # and this slot's logical token s lives in physical page
-        # page_table[b, s // page] at offset s % page. Retired slots' table
+        # Paged decode: the cache is the stack's shared page pools
+        # (L, P+1, page, kv, hd), and this layer's slot's logical token s
+        # lives at [layer, page_table[b, s // page], s % page]. Retired slots' table
         # rows all point at the trash page (index P), so their frozen-pos
         # garbage writes can never corrupt a live tenant's pages. With
         # prefix sharing, pages can additionally be mapped by several
@@ -525,17 +534,17 @@ def gqa_attention(
         # position before every decode step — a written page is always
         # refcount-1 private by the time this program runs.
         B = q.shape[0]
-        page = cache.k.shape[1]
+        page = cache.k.shape[2]
         max_pages = page_table.shape[1]
         pos_v = jnp.broadcast_to(jnp.atleast_1d(cur_pos), (B,)).astype(jnp.int32)
         wslot = pos_v % window if window else pos_v  # logical write slot
         rows = jnp.arange(B)
         pid = page_table[rows, wslot // page]  # (B,) physical page per slot
         off = wslot % page
-        ck = cache.k.at[pid, off].set(k[:, 0].astype(cache.k.dtype))
-        cv = cache.v.at[pid, off].set(v[:, 0].astype(cache.v.dtype))
-        ck = constrain(ck, ("pages", None, "kv_heads", "head_dim"), sctx)
-        cv = constrain(cv, ("pages", None, "kv_heads", "head_dim"), sctx)
+        ck = cache.k.at[layer, pid, off].set(k[:, 0].astype(cache.k.dtype))
+        cv = cache.v.at[layer, pid, off].set(v[:, 0].astype(cache.v.dtype))
+        ck = constrain(ck, _POOL_AXES, sctx)
+        cv = constrain(cv, _POOL_AXES, sctx)
         new_cache = KVCache(ck, cv)
         # Windowed layers ring-fold into the leading ceil(window/page)
         # table entries — a bounded page working set regardless of how
@@ -544,27 +553,27 @@ def gqa_attention(
         specs = None
         if cfg.attn_backend == "pallas" and not _pallas_ok(sctx):
             specs = _paged_kernel_specs(
-                sctx, B=B, H=q.shape[2], KV=ck.shape[2],
-                total_pages=ck.shape[0], batch_sharded=True,
+                sctx, B=B, H=q.shape[2], KV=ck.shape[3],
+                total_pages=ck.shape[1], batch_sharded=True,
             )
         if cfg.attn_backend == "pallas" and _pallas_ok(sctx):
             from repro.kernels import ops as _kops
 
             out = _kops.paged_decode_attention_op(
-                q, ck, cv, page_table, pos_v, n_lp=n_lp, window=window
+                q, ck, cv, page_table, pos_v, layer, n_lp=n_lp, window=window
             ).astype(dt)
         elif specs is not None:
             from repro.kernels import ops as _kops
 
             out = _kops.paged_decode_attention_sharded(
-                q, ck, cv, page_table, pos_v, n_lp=n_lp, window=window,
+                q, ck, cv, page_table, pos_v, layer, n_lp=n_lp, window=window,
                 **specs,
             ).astype(dt)
         else:
             sel = page_table[:, :n_lp]  # (B, n_lp)
             T = n_lp * page
-            kg = ck[sel].reshape(B, T, *ck.shape[2:]).astype(dt)
-            vg = cv[sel].reshape(B, T, *cv.shape[2:]).astype(dt)
+            kg = ck[layer, sel].reshape(B, T, *ck.shape[3:]).astype(dt)
+            vg = cv[layer, sel].reshape(B, T, *cv.shape[3:]).astype(dt)
             idx = jnp.arange(T, dtype=jnp.int32)
             if window:
                 k_pos = pos_v[:, None] - ((pos_v[:, None] - idx[None, :]) % window)

@@ -206,7 +206,10 @@ def apply_block(
     enc_out: jax.Array | None = None,
     page_table: jax.Array | None = None,
     chunk_len: jax.Array | None = None,
+    layer: jax.Array | None = None,
 ) -> tuple[BlockIO, dict[str, Any] | None]:
+    """One block. A paged block's ``state_raw`` holds the whole stack's
+    page pools, read and written at ``layer``."""
     x, aux = io
     st = _state_to_struct(kind, cfg, state_raw)
     if page_table is not None and kind not in paged_kv_kinds(cfg):
@@ -228,7 +231,7 @@ def apply_block(
                 mask_kind=mask_kind, window=window,
                 prefix_len=cfg.prefix_len if cfg.prefix_lm else 0,
                 cache=st, cur_pos=cur_pos, page_table=page_table,
-                chunk_len=chunk_len, sctx=sctx,
+                chunk_len=chunk_len, layer=layer, sctx=sctx,
             )
         x = x + a
         h = rmsnorm(p["ln2"], x, eps)
@@ -434,54 +437,92 @@ def apply_stack(
     page_table: jax.Array | None = None,
     chunk_len: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array, dict[str, Any] | None]:
-    """Run the whole layer stack. Returns (x, aux_loss, new_states)."""
+    """Run the whole layer stack. Returns (x, aux_loss, new_states).
+
+    With a ``page_table``, the paged blocks' KV leaves are page pools shared
+    by every slot, stacked over the layers. They ride in the scan's carry
+    and each layer updates its rows in place at its index; slicing a
+    layer's pool out as the scan's input and stacking it back as its output
+    would copy the whole pool every step. Every other state leaf is scanned
+    per layer."""
     io = BlockIO(x=x, aux=jnp.zeros((), F32))
     new_states: dict[str, Any] = {"first": {}, "groups": {}}
     want_states = mode in ("prefill", "decode", "chunk")
+    pooled = (
+        paged_kv_kinds(cfg) if page_table is not None and states is not None
+        else set()
+    )
 
     # -- unscanned prefix blocks ------------------------------------------
     for i, kind in enumerate(cfg.first_blocks):
         key = f"b{i}"
         st = states["first"][key] if states is not None else None
+        layer = None
+        if kind in pooled:  # one layer's pools: a stack of one
+            st, layer = jax.tree.map(lambda a: a[None], st), jnp.int32(0)
         io, new_st = apply_block(
             params["first"][key], cfg, kind, io, mode=mode, positions=positions,
             cur_pos=cur_pos, state_raw=st,
             mask_kind=mask_kind, sctx=sctx, enc_out=enc_out, page_table=page_table,
-            chunk_len=chunk_len,
+            chunk_len=chunk_len, layer=layer,
         )
+        if kind in pooled:
+            new_st = jax.tree.map(lambda a: a[0], new_st)
         if want_states:
             new_states["first"][key] = new_st
 
     # -- scanned pattern groups -------------------------------------------
-    def group_body(carry: BlockIO, per_layer):
-        g_params, g_states = per_layer
+    keys = [f"g{i}" for i in range(len(cfg.block_pattern))]
+    g_states_in = states["groups"] if states is not None else None
+    pool_keys = {k for k, kind in zip(keys, cfg.block_pattern) if kind in pooled}
+    pools = {k: g_states_in[k] for k in pool_keys}
+    scanned = (
+        {k: v for k, v in g_states_in.items() if k not in pool_keys}
+        if g_states_in is not None else None
+    )
+
+    def group_body(carry, per_layer):
+        io, pools = carry
+        g_params, g_states, layer = per_layer
+        pools = dict(pools)
         new_group_states = {}
         for i, kind in enumerate(cfg.block_pattern):
             key = f"g{i}"
-            st = g_states[key] if g_states is not None else None
-            carry, new_st = apply_block(
-                g_params[key], cfg, kind, carry, mode=mode, positions=positions,
+            in_pool = key in pool_keys
+            st = pools[key] if in_pool else (
+                g_states[key] if g_states is not None else None
+            )
+            io, new_st = apply_block(
+                g_params[key], cfg, kind, io, mode=mode, positions=positions,
                 cur_pos=cur_pos, state_raw=st,
                 mask_kind=mask_kind, sctx=sctx, enc_out=enc_out,
                 page_table=page_table, chunk_len=chunk_len,
+                layer=layer if in_pool else None,
             )
-            new_group_states[key] = new_st
-        return carry, (new_group_states if want_states else None)
+            if in_pool:
+                pools[key] = new_st
+            else:
+                new_group_states[key] = new_st
+        return (io, pools), (new_group_states if want_states else None)
 
     body = group_body
     if cfg.remat == "full" and mode == "train":
         body = jax.checkpoint(group_body, prevent_cse=False)
 
-    g_states_in = states["groups"] if states is not None else None
     # REPRO_UNROLL_SCANS=1: fully unroll so XLA cost_analysis (which counts
     # while bodies once) sees every layer — used to validate the analytic
     # cost model on small cells (EXPERIMENTS.md SS Dry-run validation).
     unroll = bool(int(os.environ.get("REPRO_UNROLL_SCANS", "0")))
-    io, scanned_states = jax.lax.scan(
-        body, io, (params["groups"], g_states_in), unroll=True if unroll else 1
+    n_groups = cfg.n_pattern_groups
+    (io, pools), scanned_states = jax.lax.scan(
+        body, (io, pools),
+        (params["groups"], scanned, jnp.arange(n_groups, dtype=jnp.int32)),
+        unroll=True if unroll else 1,
     )
     if want_states:
-        new_states["groups"] = scanned_states
+        new_states["groups"] = {
+            k: pools[k] if k in pool_keys else scanned_states[k] for k in keys
+        }
     if not cfg.first_blocks:
         new_states.pop("first", None)
     return io.x, io.aux, (new_states if want_states else None)

@@ -21,6 +21,15 @@ sharding context; slots, queues, and pages belong to the executor and
 memory layers. Every program takes the weights as an argument: a jitted
 function that closed over them would embed them in the program as
 constants, gigabytes of them at full width.
+
+Every program that updates the layer state and returns it donates the
+state it takes (:func:`donates_state`), except ``verify``, whose input
+speculative decoding may replay from: the model's layer scan then writes
+the page pool in place, where an undonated pool is copied whole on each
+call. The caller rebinds the state to the program's output and never
+reads the donated input again. ``pool_inplace_calls`` and
+``pool_copy_calls`` count, at dispatch, the calls of those programs on a
+paged state that donated it and that did not.
 """
 from __future__ import annotations
 
@@ -101,6 +110,37 @@ def paged_cache_bytes(
     }
 
 
+def donates_state() -> bool:
+    """Whether the programs that update the layer state donate it: on an
+    accelerator, yes. On the CPU, where the benchmark's own CPU tests plant
+    faults that hand a program's input state back as its output, the
+    programs leave their inputs readable."""
+    return jax.default_backend() != "cpu"
+
+
+class _StateProgram:
+    """A jitted program over the layer state whose calls on a paged state
+    count as in place (the state donated) or as a copy. Attributes other
+    than the call (``trace``, ``lower``) are the jitted function's."""
+
+    def __init__(self, registry: "ProgramRegistry", fn, donate_argnums=()):
+        self._registry = registry
+        self._donated = bool(donate_argnums)
+        self._jit = jax.jit(fn, donate_argnums=donate_argnums)
+
+    def __call__(self, *args):
+        reg = self._registry
+        if reg._paged:
+            if self._donated:
+                reg.pool_inplace_calls += 1
+            else:
+                reg.pool_copy_calls += 1
+        return self._jit(*args)
+
+    def __getattr__(self, name):
+        return getattr(self._jit, name)
+
+
 def _leaf_page_axis_sharded(arr, sharding) -> bool:
     """True when a pool leaf's physical page axis is mesh-sharded (the
     leading axis, behind the stacked layer axis for 5D leaves)."""
@@ -145,6 +185,12 @@ class ProgramRegistry:
         self.swap_traces = 0  # swap-out + swap-in programs
         self.cow_traces = 0  # copy-on-write fork programs (per fork count)
         self.verify_traces = 0  # one per (k-bucket, page-bucket) pair
+        self.pool_inplace_calls = 0  # state programs on a donated pool
+        self.pool_copy_calls = 0  # state programs on an undonated pool
+        donate = donates_state()
+
+        def program(fn, *state_args):
+            return _StateProgram(self, fn, state_args if donate else ())
 
         def _slot_surgery_trees():
             template = init_decode_state(cfg, 1, cache_len)["layers"]
@@ -190,7 +236,7 @@ class ProgramRegistry:
                 out["page_table"] = new_states["page_table"]
             return logits, out
 
-        self.decode = jax.jit(_decode_fn)
+        self.decode = program(_decode_fn, 1)
 
         def _prefill_fn(p, b):
             self.prefill_traces += 1
@@ -233,7 +279,7 @@ class ProgramRegistry:
 
         # slot and prompt_len are traced, so admission compiles once per
         # prefill *shape* — with bucketing, once per bucket.
-        self.admit = jax.jit(_admit_fn)
+        self.admit = program(_admit_fn, 0, 1)
 
         # -- unified-step programs (chunk streaming, slot reset, swap) -------
         def _chunk_body(params, layers, pos, tokens, slot, start, chunk_len,
@@ -292,10 +338,11 @@ class ProgramRegistry:
                     all_logits=True,
                 )
 
-        self.chunk = jax.jit(_chunk_fn)
+        self.chunk = program(_chunk_fn, 1, 2)
         # Verify program for speculative decoding: the chunk body with
-        # logits at *every* position, so one call scores a whole draft.
-        self.verify = jax.jit(_verify_fn)
+        # logits at *every* position, so one call scores a whole draft. Its
+        # input state stays readable: a partial accept replays from it.
+        self.verify = program(_verify_fn)
         # Position-only fixup for partial acceptance on archs whose caches
         # tolerate garbage past the accepted position (dense / MLA).
         self.setpos = jax.jit(lambda pos, slot, val: pos.at[slot].set(val))
@@ -320,7 +367,7 @@ class ProgramRegistry:
             )
             return new_layers, pos.at[slot].set(pos_val)
 
-        self.reset = jax.jit(_reset_fn)
+        self.reset = program(_reset_fn, 0, 1)
 
         if paged:
 
@@ -368,7 +415,7 @@ class ProgramRegistry:
 
                 return jax.tree.map(leaf, caps, layers, self._layer_shardings)
 
-            self.cow = jax.jit(_cow_fn)
+            self.cow = program(_cow_fn, 0)
 
             def _swap_out_fn(layers, page_ids, slot):
                 self.swap_traces += 1
@@ -398,7 +445,7 @@ class ProgramRegistry:
                 return new_layers, pos.at[slot].set(pos_val)
 
             self.swap_out = jax.jit(_swap_out_fn)
-            self.swap_in = jax.jit(_swap_in_fn)
+            self.swap_in = program(_swap_in_fn, 0, 1)
 
         def _sample_fn(logits, temps, key):
             # Over a vocabulary split across chips, the choice reduces

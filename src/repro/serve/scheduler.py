@@ -650,6 +650,10 @@ class Scheduler:
             "host_time_s": self.host_time_s,
             "decode_pages_walked": self.decode_pages_walked,
             "decode_pages_table": self.decode_pages_table,
+            # Program calls on the paged state that updated the pool in
+            # place (state donated) and that took it undonated (a copy).
+            "pool_inplace_calls": self.programs.pool_inplace_calls,
+            "pool_copy_calls": self.programs.pool_copy_calls,
         }
         out["mesh"] = (
             None if self.sctx.mesh is None else dict(self.sctx.mesh.shape)

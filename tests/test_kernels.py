@@ -88,6 +88,59 @@ class TestFlashAttention:
         assert float(jnp.max(jnp.abs(o1 - o2))) < 1e-5
 
 
+class TestStackedPagedKernels:
+    """The paged kernels read a layer of the whole stack's pools at a layer
+    index: at the first and the last layer of a pool whose layers hold
+    different data, each equals the reference on that layer alone."""
+
+    L, P, PAGE, KV, G, D, MP = 3, 12, 8, 2, 4, 16, 5
+
+    def _stack(self, seed):
+        rng = np.random.default_rng(seed)
+        shape = (self.L, self.P + 1, self.PAGE, self.KV, self.D)
+        # Layer l's values sit near 3 * l: every layer reads differently.
+        off = 3.0 * np.arange(self.L).reshape(-1, 1, 1, 1, 1)
+        kp = jnp.asarray(rng.normal(size=shape) + off, jnp.float32)
+        vp = jnp.asarray(rng.normal(size=shape) - off, jnp.float32)
+        pt = np.full((2, self.MP), self.P, np.int32)  # row P: the trash page
+        pt[0, :4] = [3, 7, 0, 9]
+        pt[1, :2] = [5, 11]
+        return rng, kp, vp, jnp.asarray(pt)
+
+    @pytest.mark.parametrize("layer", [0, L - 1], ids=["first", "last"])
+    def test_decode_matches_ref(self, layer):
+        rng, kp, vp, pt = self._stack(0)
+        q = jnp.asarray(rng.normal(size=(2, 1, self.KV * self.G, self.D)), jnp.float32)
+        cur = jnp.asarray([27, 12], jnp.int32)
+        o = ops.paged_decode_attention_op(
+            q, kp, vp, pt, cur, jnp.asarray(layer, jnp.int32), n_lp=self.MP
+        )
+        oref = ref.paged_decode_attention_ref(
+            q.reshape(2, self.KV, self.G, self.D), kp[layer], vp[layer], pt, cur,
+            n_lp=self.MP,
+        )
+        err = float(jnp.max(jnp.abs(o.reshape(oref.shape) - oref)))
+        assert err < _tol(jnp.float32), err
+
+    @pytest.mark.parametrize("layer", [0, L - 1], ids=["first", "last"])
+    def test_chunk_matches_ref(self, layer):
+        rng, kp, vp, pt = self._stack(1)
+        C = 8
+        q = jnp.asarray(rng.normal(size=(1, C, self.KV * self.G, self.D)), jnp.float32)
+        start = jnp.asarray([19], jnp.int32)
+        o = ops.paged_chunk_attention_op(
+            q, kp, vp, pt[:1], start, jnp.asarray(layer, jnp.int32), n_lp=self.MP
+        )
+        qf = q.reshape(1, C, self.KV, self.G, self.D).transpose(0, 2, 1, 3, 4)
+        oref = ref.paged_chunk_attention_ref(
+            qf.reshape(1, self.KV, C * self.G, self.D), kp[layer], vp[layer],
+            pt[:1], start, n_lp=self.MP, group=self.G,
+        )
+        oref = oref.reshape(1, self.KV, C, self.G, self.D).transpose(0, 2, 1, 3, 4)
+        err = float(jnp.max(jnp.abs(o - oref.reshape(o.shape))))
+        assert err < _tol(jnp.float32), err
+
+
 class TestDecodeAttention:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     @pytest.mark.parametrize("window", [0, 64])

@@ -191,7 +191,7 @@ class TestSpecResolution:
         )
         assert specs is not None
         assert specs["localize_pages"] is False
-        assert specs["pool_spec"] == P(None, None, None, None)
+        assert specs["pool_spec"] == P(None, None, None, None, None)  # stacked pools
 
     @needs_2dev
     def test_partitioned_pool_localizes(self):
@@ -205,7 +205,7 @@ class TestSpecResolution:
         )
         assert specs is not None
         assert specs["localize_pages"] is True
-        assert specs["pool_spec"] == P("data", None, None, None)
+        assert specs["pool_spec"] == P(None, "data", None, None, None)
         assert specs["table_spec"] == P("data", None)
 
 

@@ -3,7 +3,13 @@ paged-vs-static greedy token identity across the model zoo's state
 families, pool-capacity admission backpressure, shape-stable decode under
 page growth, bucketed-prefill compile counts, and the paged decode kernel
 vs its XLA gather reference."""
+import json
+import os
+import re
+import subprocess
+import sys
 from dataclasses import replace
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -646,3 +652,112 @@ class TestPagedGraftAndKernel:
                 sched.submit(Request(p, max_new_tokens=4))
             toks.append([rs.tokens for rs in sched.run()])
         assert toks[0] == toks[1]
+
+
+# ==========================================================================
+# The step programs update the stacked pool in place
+# ==========================================================================
+def _pool_hlo(backend: str, mesh_shape) -> dict:
+    """Compile the registry's decode and chunk programs on the CPU for a
+    small paged config, the caller having turned state donation on, and
+    read each program's HLO: its pool parameters, how many of them alias
+    an output, and its dynamic-slice / dynamic-update-slice ops shaped as a
+    whole layer of the pool (a layer's slice out of the stack, or the stack
+    it is written back into). Four KV heads, so a 1x4 mesh splits them."""
+    base = get_config("llama3.2-3b").reduced()
+    cfg = replace(base, attn_backend=backend, n_kv_heads=base.n_heads)
+    params = init_params(lm.model_schema(cfg), jax.random.PRNGKey(0))
+    sched = Scheduler(
+        cfg, params, ShardingCtx.null(),
+        SchedulerConfig(n_slots=2, cache_len=64, page_size=8, chunk_budget=16,
+                        mesh_shape=mesh_shape),
+    )
+    st, pr = sched._states, sched.programs
+    i32 = lambda v: jnp.asarray(v, jnp.int32)
+    texts = {
+        "decode": pr.decode.lower(
+            sched.params, st, jnp.zeros((2, 1), jnp.int32), jnp.ones((2,), bool)
+        ),
+        "chunk": pr.chunk.lower(
+            sched.params, st["layers"], st["pos"], jnp.zeros((1, 16), jnp.int32),
+            i32(0), i32(0), i32(16), i32(sched.mem.pt[0, :2]),
+        ),
+    }
+    pool = jax.tree.leaves(st["layers"])[0]
+    L, rows, page, kv, hd = pool.shape
+    kv_local = kv // (mesh_shape[1] if mesh_shape else 1)
+    layer_dims = f"{rows},{page},{kv_local},{hd}"
+
+    def is_layer(dims: str) -> bool:
+        return dims in (layer_dims, "1," + layer_dims)
+
+    out = {}
+    for name, lowered in texts.items():
+        txt = lowered.compile().as_text()
+        head = txt.splitlines()[0]
+        params_part = head.split("entry_computation_layout={(", 1)[1].split(")->", 1)[0]
+        shapes = re.findall(r"(\w+)\[([\d,]*)\]", params_part)
+        pool_params = [
+            i for i, (dt, dims) in enumerate(shapes)
+            if dt == "bf16" and dims == f"{L},{rows},{page},{kv_local},{hd}"
+        ]
+        aliased = [i for i in pool_params if re.search(rf"\({i}, \{{\}}, may-alias\)", head)]
+        # A slice's own shape; an update's, that of the value it writes.
+        dims_of = dict(re.findall(r"%([\w.-]+) = \w+\[([\d,]*)\]", txt))
+        sliced = [
+            l.strip()[:120] for l in txt.splitlines()
+            if (m := re.search(r"= \w+\[([\d,]*)\]\S* dynamic-slice\(", l)) and is_layer(m[1])
+            or (m := re.search(r"dynamic-update-slice\(%[\w.-]+, %([\w.-]+)", l))
+            and is_layer(dims_of.get(m[1], ""))
+        ]
+        out[name] = {
+            "pool_params": len(pool_params), "aliased": len(aliased),
+            "whole_layer_slices": sliced,
+        }
+    return out
+
+
+_MESH_HLO = """
+import json, sys
+sys.path.insert(0, {tests!r})
+from repro.serve import programs
+programs.donates_state = lambda: True
+from test_serve_pages import _pool_hlo
+print(json.dumps({{b: _pool_hlo(b, (1, 4)) for b in ("xla", "pallas")}}))
+"""
+
+
+@pytest.fixture(scope="module")
+def mesh_pool_hlo():
+    """Both backends' findings on a forced four-device CPU mesh, from one
+    child process (the device count is fixed when JAX starts)."""
+    root = Path(__file__).resolve().parents[1]
+    env = dict(
+        os.environ, JAX_PLATFORMS="cpu",
+        XLA_FLAGS="--xla_force_host_platform_device_count=4",
+        PYTHONPATH=os.pathsep.join([str(root / "src"), os.environ.get("PYTHONPATH", "")]),
+    )
+    code = _MESH_HLO.format(tests=str(root / "tests"))
+    p = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True,
+                       env=env, timeout=600, cwd=root)
+    assert p.returncode == 0, p.stderr[-3000:]
+    return json.loads(p.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+@pytest.mark.parametrize("devices", ["one-device", "mesh-1x4"])
+def test_step_programs_update_the_pool_in_place(backend, devices, monkeypatch, request):
+    """The decode and chunk programs carry the stacked pool through the
+    layer scan: no layer's pool is sliced out and written back, and the
+    donated pool parameters alias the pool outputs."""
+    if devices == "one-device":
+        from repro.serve import programs
+
+        monkeypatch.setattr(programs, "donates_state", lambda: True)
+        found = _pool_hlo(backend, None)
+    else:
+        found = request.getfixturevalue("mesh_pool_hlo")[backend]
+    for name, f in found.items():
+        assert f["pool_params"] == 2, (name, f)  # K and V of the one group
+        assert f["aliased"] == f["pool_params"], (name, f)
+        assert not f["whole_layer_slices"], (name, f)

@@ -379,3 +379,73 @@ class TestSpecComposition:
             assert key in st_
         assert st_["drafted_tokens"] >= st_["accepted_tokens"]
         assert sched.result(rid).drafted >= sched.result(rid).accepted
+
+
+# ==========================================================================
+# Donated state: no caller reads a program's input state after the call
+# ==========================================================================
+class TestDonatedState:
+    @pytest.mark.parametrize(
+        "arch, spec",
+        [
+            ("llama3.2-3b", True),  # verify (undonated) and position fixups
+            ("llama3.2-3b", False),  # no verify: every pool call in place
+            ("recurrentgemma-2b", True),  # replay from the pre-verify state
+        ],
+        ids=["dense-spec", "dense-plain", "windowed-spec"],
+    )
+    def test_churn_stays_identical_with_donated_state(self, arch, spec, monkeypatch):
+        """With the state donated to every program that updates it (verify
+        aside), a run that forks a shared page, swaps a request out and in,
+        and verifies drafts (replaying rejected ones where the state
+        advanced) emits the static engine's tokens; the donated inputs are
+        gone after each call, so a caller that read one would fail. Verify
+        calls are the only pool calls that copy."""
+        from repro.serve import programs
+
+        monkeypatch.setattr(programs, "donates_state", lambda: True)
+        cfg, params = _params_for(arch)
+        shared = _patterned(cfg, 16, period=5, seed=8)
+        plen = 26 if arch == "llama3.2-3b" else 33
+        prompts = [
+            np.concatenate([shared, _patterned(cfg, plen - 16, period=3, seed=s)])
+            for s in (11, 12)
+        ]
+        batch = {"tokens": np.stack(prompts)}
+        ref = Engine(
+            cfg, params, ShardingCtx.null(),
+            ServeConfig(max_new_tokens=10, cache_len=64, page_size=8, chunk_budget=16),
+        ).generate_static(batch).tokens
+        seqs = [np.concatenate([prompts[i], ref[i]]) for i in range(2)]
+        sched = Scheduler(
+            cfg, params, ShardingCtx.null(),
+            SchedulerConfig(n_slots=2, cache_len=64, page_size=8, n_pages=7,
+                            chunk_budget=16, preemption="swap", speculative=spec,
+                            draft_k=4,
+                            drafter=_MultiOracleNoise(seqs, [0, 1, 2, 3] * 8)),
+        )
+        r0 = sched.submit(Request(prompts[0], max_new_tokens=10))
+        for _ in range(3):  # stream prompt 0 in; its full pages get indexed
+            sched.step()
+        r1 = sched.submit(Request(prompts[1], max_new_tokens=10))
+        before = jax.tree.leaves(sched._states["layers"])
+        sched.step()
+        assert all(x.is_deleted() for x in before), "state was not donated"
+        if arch == "llama3.2-3b":
+            # Fork the first page r1 adopted: the copy-on-write program runs
+            # on the donated pool mid-run.
+            slot = next(s for s, rs in sched._active.items() if rs.rid == r1)
+            forks = sched.mem.prepare_write(slot, 0, 1)
+            assert forks, "r1 must hold a shared page"
+            sched._apply_cow(forks)
+            assert sched.cow_traces == 1
+        sched.run()
+        assert [sched.result(r).tokens for r in (r0, r1)] == [list(t) for t in ref]
+        st = sched.stats()
+        assert st["preemptions"] > 0 and st["swap_traces"] > 0
+        assert (st["spec_steps"] > 0) == spec
+        if arch == "recurrentgemma-2b":
+            assert st["spec_replays"] > 0
+        assert st["pool_copy_calls"] == st["spec_steps"]
+        in_place = st["decode_steps"] + st["chunk_steps"] + st["spec_replays"]
+        assert st["pool_inplace_calls"] > in_place

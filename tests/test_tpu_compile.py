@@ -5,7 +5,9 @@ shapes the TPU compiler refuses, so these tests compile each kernel for a
 described, not attached, v5e chip at llama3.2-3b widths: 24 query heads over
 8 KV heads, head_dim 128; the paged decode kernel also at the Qwen3-8B chat
 cell's shapes (32 slots of 4 query heads per KV head, a 160-page table over
-a 1,537-row pool). Nothing runs; a compile that the chip's compiler
+a 1,537-row pool). Both paged kernels compile in both forms: on one layer's
+pool, and on the whole layer stack's pools read at a traced layer index, as
+the model's layer scan calls them. Nothing runs; a compile that the chip's compiler
 would refuse fails here. The topology is described inside a fixture, never
 while the module is imported (only one process at a time may load the TPU
 library).
@@ -55,10 +57,19 @@ def _shape(sharding, shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _pool(one_chip):
-    return _shape(one_chip, (POOL_PAGES + 1, PAGE, KV, D))
+LAYERS = 18  # the stacked form: Qwen3-8B chat's 18 layers
+FORMS = ["pool", "stacked"]
 
 
+def _pools(one_chip, form, rows):
+    """The kernel's pool operands and its layer index (None for one pool)."""
+    lead = (LAYERS,) if form == "stacked" else ()
+    pool = _shape(one_chip, lead + (rows, PAGE, KV, D))
+    layer = _shape(one_chip, (), jnp.int32) if form == "stacked" else None
+    return pool, layer
+
+
+@pytest.mark.parametrize("form", FORMS)
 @pytest.mark.parametrize(
     "b, g, max_pages, pool_rows",
     [
@@ -67,28 +78,30 @@ def _pool(one_chip):
     ],
     ids=["llama3.2-3b", "qwen3-8b-chat"],
 )
-def test_paged_decode_compiles(one_chip, b, g, max_pages, pool_rows):
-    pool = _shape(one_chip, (pool_rows, PAGE, KV, D))
+def test_paged_decode_compiles(one_chip, b, g, max_pages, pool_rows, form):
+    pool, layer = _pools(one_chip, form, pool_rows)
     compiled = _compile(
-        lambda q, k, v, pt, pos: dec.paged_decode_attention(
-            q, k, v, pt, pos, n_lp=max_pages, interpret=False
+        lambda q, k, v, pt, pos, ly: dec.paged_decode_attention(
+            q, k, v, pt, pos, ly, n_lp=max_pages, interpret=False
         ),
         _shape(one_chip, (b, KV, g, D)), pool, pool,
         _shape(one_chip, (b, max_pages), jnp.int32),
-        _shape(one_chip, (b,), jnp.int32),
+        _shape(one_chip, (b,), jnp.int32), layer,
     )
     # the kernel's op carries its own name into the compiled program
     assert "%paged_decode_attention" in compiled.as_text()
 
 
-def test_paged_chunk_compiles(one_chip):
+@pytest.mark.parametrize("form", FORMS)
+def test_paged_chunk_compiles(one_chip, form):
+    pool, layer = _pools(one_chip, form, POOL_PAGES + 1)
     compiled = _compile(
-        lambda q, k, v, pt, start: dec.paged_chunk_attention(
-            q, k, v, pt, start, n_lp=MAX_PAGES, group=G, interpret=False
+        lambda q, k, v, pt, start, ly: dec.paged_chunk_attention(
+            q, k, v, pt, start, ly, n_lp=MAX_PAGES, group=G, interpret=False
         ),
-        _shape(one_chip, (1, KV, CHUNK * G, D)), _pool(one_chip), _pool(one_chip),
+        _shape(one_chip, (1, KV, CHUNK * G, D)), pool, pool,
         _shape(one_chip, (1, MAX_PAGES), jnp.int32),
-        _shape(one_chip, (1,), jnp.int32),
+        _shape(one_chip, (1,), jnp.int32), layer,
     )
     assert "%paged_chunk_attention" in compiled.as_text()
 
