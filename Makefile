@@ -4,10 +4,12 @@
 test:
 	./scripts/test.sh
 
+# Memento's host-side claims (B1-B5, B12); serving is measured on the chip
+# (BENCHMARK.json, PERF.md).
 bench:
 	PYTHONPATH=src python benchmarks/run.py
 
-# B1-B5 at tiny sizes: the CI end-to-end exercise of the experiment layer.
+# B1-B5 at their smoke sizes, as CI runs them.
 bench-smoke:
 	PYTHONPATH=src python benchmarks/run.py --smoke
 

@@ -13,10 +13,6 @@ MLXP's result queries, NSML's live monitoring):
   results: ``compare(frame, rows=..., cols=..., agg=..., baseline=...)``
   with delta/ratio columns and markdown/CSV renderers, plus
   ``compare_frames`` for cross-run A/B diffs (one column per run).
-* :mod:`repro.analysis.trajectory` — a queryable store over the versioned
-  ``benchmarks/records/BENCH_<n>.json`` perf records: filter by
-  mode/benchmark, extract series across records, and detect regressions
-  against the same-commit-lineage baseline with per-metric thresholds.
 * :mod:`repro.analysis.dash` — a stdlib-only live dashboard
   (:class:`Dashboard`, ``http.server`` + JSON/SSE endpoints) fed by
   :class:`AnalysisNotificationProvider`, which tees ``Memento.stream`` /
@@ -24,32 +20,20 @@ MLXP's result queries, NSML's live monitoring):
   (per-host throughput, queue depth, ETA, failure drill-down with real
   tracebacks).
 
-CLI: ``python -m repro.analysis {table,trajectory,regressions,dash}``.
+CLI: ``python -m repro.analysis {table,dash}``.
 """
 from .dash import AnalysisNotificationProvider, Dashboard
 from .metrics import Examiner, MetricFrame, MetricRecord, MetricSpec
 from .tables import Table, compare, compare_frames
-from .trajectory import (
-    BenchRecord,
-    Regression,
-    RegressionPolicy,
-    Trajectory,
-    detect_regressions,
-)
 
 __all__ = [
     "AnalysisNotificationProvider",
-    "BenchRecord",
     "Dashboard",
     "Examiner",
     "MetricFrame",
     "MetricRecord",
     "MetricSpec",
-    "Regression",
-    "RegressionPolicy",
     "Table",
-    "Trajectory",
     "compare",
     "compare_frames",
-    "detect_regressions",
 ]

@@ -1,13 +1,10 @@
-"""``python -m repro.analysis`` — tables, trajectories, regressions, dash.
+"""``python -m repro.analysis`` — tables and the live dashboard.
 
 Subcommands:
 
 * ``table``       render a grouped comparison table from a results CSV
-                  (``ResultSet.to_csv``) or the latest benchmark record;
-                  ``--diff R1 R2 ...`` diffs runs with ratio/delta columns
-* ``trajectory``  list benchmark records, or one metric's series across them
-* ``regressions`` diff a benchmark record against its lineage baseline;
-                  ``--strict`` exits nonzero when regressions exist (CI)
+                  (``ResultSet.to_csv``); ``--diff A.csv B.csv ...`` diffs
+                  runs with ratio/delta columns
 * ``dash``        serve the live dashboard over an event journal
 
 Output is plain text/markdown/CSV on stdout — the table renderers are the
@@ -17,20 +14,12 @@ identical token for token.
 from __future__ import annotations
 
 import argparse
-import json
-import re
+import os
 import sys
 from typing import Any
 
 from .metrics import MetricFrame, _as_float
 from .tables import AGGREGATORS, compare, compare_frames
-from .trajectory import (
-    DEFAULT_RECORDS_DIR,
-    RegressionPolicy,
-    Trajectory,
-    diff_latest,
-    load_policies,
-)
 
 
 def _coerce(label: str) -> Any:
@@ -60,50 +49,23 @@ def _render(table: Any, fmt: str) -> str:
     return str(table)
 
 
-def _diff_frames(args: argparse.Namespace, metric: str):
-    """Resolve ``--diff`` run tokens into labeled frames.
-
-    A token of digits names a benchmark record in ``--records-dir``;
-    anything else is read as a ``ResultSet.to_csv`` file. Record frames
-    carry only ``metric``; CSV frames carry every metric in the file.
-    """
-    from .trajectory import Trajectory
-
-    traj = None
-    pairs: list[tuple[str, MetricFrame]] = []
-    all_records = True
-    for tok in args.diff:
-        if re.fullmatch(r"\d+", tok):
-            if traj is None:
-                traj = Trajectory.load(args.records_dir)
-            rec = traj.get(int(tok))
-            if rec is None:
-                raise SystemExit(
-                    f"error: no record {tok} in {args.records_dir}"
-                )
-            frame = Trajectory([rec]).to_frame(metrics=(metric,))
-            pairs.append((f"record {rec.record}", frame))
-        else:
-            all_records = False
-            pairs.append((tok, MetricFrame.from_results_csv(tok)))
-    return pairs, all_records
-
-
 def cmd_table_diff(args: argparse.Namespace) -> int:
-    if args.csv or args.latest:
-        raise SystemExit("error: --diff is exclusive with --csv/--latest")
+    if args.csv:
+        raise SystemExit("error: --diff is exclusive with --csv")
     if len(args.diff) < 2:
         raise SystemExit("error: --diff needs at least two runs")
-    if args.metric and len(args.metric) > 1:
-        raise SystemExit("error: --diff compares exactly one metric")
-    metric = args.metric[0] if args.metric else "tok_s"
-    pairs, all_records = _diff_frames(args, metric)
-    rows = args.rows or (["benchmark"] if all_records else None)
-    if not rows:
-        raise SystemExit("error: --rows is required when --diff includes CSVs")
+    if not args.rows:
+        raise SystemExit("error: --rows is required with --diff")
+    if not args.metric or len(args.metric) > 1:
+        raise SystemExit("error: --diff compares exactly one --metric")
+    missing = [p for p in args.diff if not os.path.exists(p)]
+    if missing:
+        raise SystemExit(f"error: no results CSV {missing[0]}")
+    metric = args.metric[0]
+    pairs = [(p, MetricFrame.from_results_csv(p)) for p in args.diff]
     table = compare_frames(
-        pairs, rows=rows, metric=metric, agg=args.agg,
-        title=args.title or f"{metric}: " + " vs ".join(lb for lb, _ in pairs),
+        pairs, rows=args.rows, metric=metric, agg=args.agg,
+        title=args.title or f"{metric}: " + " vs ".join(args.diff),
     )
     if args.baseline:
         table.baseline = _resolve_baseline(args.baseline, table.col_labels)
@@ -114,34 +76,17 @@ def cmd_table_diff(args: argparse.Namespace) -> int:
 def cmd_table(args: argparse.Namespace) -> int:
     if args.diff:
         return cmd_table_diff(args)
-    if bool(args.csv) == bool(args.latest):
-        raise SystemExit("error: pass exactly one of --csv PATH or --latest")
-    if args.csv:
-        frame = MetricFrame.from_results_csv(args.csv)
-        default_rows = None
-    else:
-        traj = Trajectory.load(args.records_dir)
-        latest = traj.latest(args.mode or None)
-        if latest is None:
-            raise SystemExit(f"error: no records in {args.records_dir}")
-        frame = Trajectory([latest]).to_frame(
-            metrics=tuple(args.metric) if args.metric else ("tok_s", "wall_s")
-        )
-        default_rows = ["benchmark"]
-        if not args.title:
-            args.title = (
-                f"Benchmark record {latest.record} "
-                f"({latest.mode}, {latest.commit[:12]})"
-            )
-    rows = args.rows or default_rows
-    if not rows:
+    if not args.csv:
+        raise SystemExit("error: pass --csv PATH or --diff PATH PATH ...")
+    if not args.rows:
         raise SystemExit("error: --rows is required with --csv")
+    frame = MetricFrame.from_results_csv(args.csv)
     metric = None
     if args.metric and (args.cols or len(args.metric) == 1):
         metric = args.metric[0]
     table = compare(
         frame,
-        rows=rows,
+        rows=args.rows,
         cols=args.cols or None,
         metric=metric,
         agg=args.agg,
@@ -152,85 +97,12 @@ def cmd_table(args: argparse.Namespace) -> int:
     return 0
 
 
-def cmd_trajectory(args: argparse.Namespace) -> int:
-    traj = Trajectory.load(args.records_dir).filter(
-        mode=args.mode or None, benchmark=args.benchmark or None
-    )
-    if not len(traj):
-        print(f"no records in {args.records_dir}", file=sys.stderr)
-        return 1
-    if args.series:
-        pts = traj.series(args.series, metric=args.metric_name)
-        if args.json:
-            print(json.dumps(
-                {"name": args.series, "metric": args.metric_name,
-                 "series": [{"record": n, "value": v} for n, v in pts]}
-            ))
-        else:
-            print(f"{args.series} {args.metric_name}:")
-            for n, v in pts:
-                print(f"  record {n}: {v:g}")
-        return 0
-    if args.json:
-        print(json.dumps([
-            {"record": r.record, "mode": r.mode, "commit": r.commit,
-             "timestamp": r.timestamp, "rows": len(r.rows)}
-            for r in traj
-        ]))
-    else:
-        for r in traj:
-            print(
-                f"record {r.record}  mode={r.mode}  commit={r.commit[:12]}  "
-                f"rows={len(r.rows)}  {r.timestamp}"
-            )
-    return 0
-
-
-def _policies(args: argparse.Namespace) -> tuple[RegressionPolicy, ...]:
-    if not args.policy:
-        # No CLI overrides: thresholds come from the checked-in policy
-        # file (benchmarks/policy.json by default), falling back to the
-        # built-in >30% tok/s rule when no file exists.
-        return load_policies(getattr(args, "policy_file", None))
-    out = []
-    for p in args.policy:
-        # metric[:max_drop[:lower_is_better]] e.g. tok_s:0.3 or itl_p50_s:0.5:lower
-        parts = p.split(":")
-        out.append(
-            RegressionPolicy(
-                metric=parts[0],
-                max_drop=float(parts[1]) if len(parts) > 1 else 0.30,
-                higher_is_better=not (len(parts) > 2 and parts[2] == "lower"),
-            )
-        )
-    return tuple(out)
-
-
-def cmd_regressions(args: argparse.Namespace) -> int:
-    new, base, regs = diff_latest(
-        args.records_dir, record=args.record, policies=_policies(args)
-    )
-    if new is None:
-        print(f"no records in {args.records_dir}", file=sys.stderr)
-        return 1
-    if base is None:
-        print(f"record {new.record}: no comparable baseline (first of its "
-              f"mode, or every earlier record is from a diverged branch)")
-        return 0
-    for r in regs:
-        print(r.warn_line())
-    if not regs:
-        print(f"record {new.record} vs record {base.record}: no regressions")
-    return 1 if (regs and args.strict) else 0
-
-
 def cmd_dash(args: argparse.Namespace) -> int:
     from .dash import serve_journal
 
     dash, prov = serve_journal(
         args.journal, host=args.host, port=args.port,
         follow=not args.no_follow, total=args.total,
-        records_dir=args.records_dir,
     )
     print(f"dashboard: {dash.url}  (journal: {args.journal})")
     try:
@@ -246,20 +118,15 @@ def cmd_dash(args: argparse.Namespace) -> int:
 def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(
         prog="python -m repro.analysis",
-        description="Analysis over Memento results and benchmark records.",
+        description="Analysis over Memento results and event journals.",
     )
     sub = ap.add_subparsers(dest="cmd", required=True)
 
     t = sub.add_parser("table", help="grouped comparison table")
     t.add_argument("--csv", help="ResultSet.to_csv file to analyze")
-    t.add_argument("--latest", action="store_true",
-                   help="use the latest benchmark record instead of a CSV")
-    t.add_argument("--diff", nargs="+", metavar="RUN",
-                   help="diff two or more runs (record numbers from "
-                   "--records-dir and/or ResultSet CSV paths): one column "
+    t.add_argument("--diff", nargs="+", metavar="CSV",
+                   help="diff two or more ResultSet CSV files: one column "
                    "per run, ratio/delta vs the first")
-    t.add_argument("--records-dir", default=DEFAULT_RECORDS_DIR)
-    t.add_argument("--mode", default="", help="with --latest: restrict mode")
     t.add_argument("--rows", nargs="+", help="param keys for table rows")
     t.add_argument("--cols", nargs="+", help="param keys for table columns "
                    "(default: one column per metric)")
@@ -271,35 +138,11 @@ def build_parser() -> argparse.ArgumentParser:
     t.add_argument("--format", default="md", choices=("md", "csv", "text"))
     t.set_defaults(fn=cmd_table)
 
-    tr = sub.add_parser("trajectory", help="query benchmark records")
-    tr.add_argument("--records-dir", default=DEFAULT_RECORDS_DIR)
-    tr.add_argument("--mode", default="")
-    tr.add_argument("--benchmark", default="",
-                    help="restrict to rows whose name starts with this")
-    tr.add_argument("--series", help="print one benchmark row's series")
-    tr.add_argument("--metric", dest="metric_name", default="tok_s")
-    tr.add_argument("--json", action="store_true")
-    tr.set_defaults(fn=cmd_trajectory)
-
-    rg = sub.add_parser("regressions", help="diff a record vs its baseline")
-    rg.add_argument("--records-dir", default=DEFAULT_RECORDS_DIR)
-    rg.add_argument("--record", type=int, help="record number (default: latest)")
-    rg.add_argument("--policy", nargs="+",
-                    help="metric[:max_drop[:lower]] e.g. tok_s:0.3 itl_p50_s:0.5:lower")
-    rg.add_argument("--policy-file",
-                    help="JSON policy file (default: benchmarks/policy.json "
-                    "when present); --policy flags override it")
-    rg.add_argument("--strict", action="store_true",
-                    help="exit 1 when regressions are found (CI gate)")
-    rg.set_defaults(fn=cmd_regressions)
-
     d = sub.add_parser("dash", help="serve the live dashboard over a journal")
     d.add_argument("--journal", required=True, help="event journal (JSONL)")
     d.add_argument("--host", default="127.0.0.1")
     d.add_argument("--port", type=int, default=8321)
     d.add_argument("--total", type=int, help="expected task total (for ETA)")
-    d.add_argument("--records-dir", default=DEFAULT_RECORDS_DIR,
-                   help="perf records dir backing /api/trajectory sparklines")
     d.add_argument("--no-follow", action="store_true",
                    help="replay once, don't tail the journal")
     d.set_defaults(fn=cmd_dash)

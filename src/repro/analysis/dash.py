@@ -18,9 +18,6 @@ every engine event — ``task_started``/``task_finished``/``task_failed`` from
 * ``GET /api/state``      the aggregate snapshot as JSON
 * ``GET /api/events``     the journal tail (``?since=<cursor>`` to page)
 * ``GET /api/stream``     Server-Sent Events: state snapshots pushed ~1/s
-* ``GET /api/trajectory`` per-benchmark metric series across the persisted
-  ``benchmarks/records/`` perf records (``?metric=&mode=&benchmark=``) —
-  rendered as inline sparklines on the fleet view
 
 Pair with ``python -m repro.analysis dash --journal <path>`` to watch a run
 owned by another process (or a whole fleet writing to one shared journal).
@@ -246,33 +243,6 @@ class AnalysisNotificationProvider(NotificationProvider):
             return self._seq, out
 
 
-def trajectory_payload(
-    records_dir: str | Path | None = None,
-    metric: str = "tok_s",
-    mode: str | None = None,
-    benchmark: str | None = None,
-) -> dict[str, Any]:
-    """The ``/api/trajectory`` body: per-benchmark series of ``metric``
-    across the persisted perf records, oldest first — what the dashboard
-    draws as sparklines. Loaded per call; records dirs are tiny."""
-    from .trajectory import DEFAULT_RECORDS_DIR, Trajectory
-
-    d = str(records_dir or DEFAULT_RECORDS_DIR)
-    traj = Trajectory.load(d).filter(mode=mode, benchmark=benchmark)
-    series = {}
-    for name in traj.names(metric):
-        pts = traj.series(name, metric=metric)
-        if pts:
-            series[name] = [{"record": n, "value": v} for n, v in pts]
-    return {
-        "records_dir": d,
-        "metric": metric,
-        "modes": traj.modes(),
-        "records": [r.record for r in traj],
-        "series": series,
-    }
-
-
 _INDEX_HTML = """<!doctype html>
 <html lang="en"><head><meta charset="utf-8">
 <title>memento fleet</title>
@@ -310,10 +280,6 @@ _INDEX_HTML = """<!doctype html>
 <h1>queue</h1>
 <table id="queue"><thead><tr>
   <th>host</th><th>claimed</th><th>done</th>
-</tr></thead><tbody></tbody></table>
-<h1>perf trajectory <span class="muted" id="trajmeta"></span></h1>
-<table id="traj"><thead><tr>
-  <th>benchmark</th><th>trend</th><th>latest</th><th>records</th>
 </tr></thead><tbody></tbody></table>
 <h1>failures <span class="muted">(click to expand traceback)</span></h1>
 <div id="failures" class="muted">none</div>
@@ -363,38 +329,11 @@ function render(s) {
         `</details>`).join("")
     : "none";
 }
-function spark(pts, w = 120, h = 24) {
-  if (pts.length < 2) return `<span class="muted">-</span>`;
-  const vs = pts.map(p => p.value);
-  const lo = Math.min(...vs), hi = Math.max(...vs), span = hi - lo || 1;
-  const xy = vs.map((v, i) =>
-    `${(1 + i / (vs.length - 1) * (w - 2)).toFixed(1)},` +
-    `${(h - 2 - (v - lo) / span * (h - 4)).toFixed(1)}`);
-  const up = vs[vs.length - 1] >= vs[0];
-  return `<svg width="${w}" height="${h}" viewBox="0 0 ${w} ${h}">` +
-    `<polyline fill="none" stroke="${up ? "#8fd9a8" : "#ff8a8a"}" ` +
-    `stroke-width="1.5" points="${xy.join(" ")}"/></svg>`;
-}
-async function loadTraj() {
-  try {
-    const t = await (await fetch("/api/trajectory")).json();
-    const names = Object.keys(t.series);
-    document.getElementById("trajmeta").textContent =
-      `(${t.metric} across ${t.records.length} records)`;
-    document.querySelector("#traj tbody").innerHTML = names.map(n => {
-      const pts = t.series[n];
-      return `<tr><td>${esc(n)}</td><td>${spark(pts)}</td>` +
-        `<td>${fmt(pts[pts.length - 1].value)}</td>` +
-        `<td>${pts.length}</td></tr>`;
-    }).join("") || `<tr><td class="muted">no benchmark records</td></tr>`;
-  } catch (e) { /* records dir optional; leave the section empty */ }
-}
 async function poll() {
   try { render(await (await fetch("/api/state")).json()); }
   catch (e) { document.getElementById("stale").style.display = "inline"; }
 }
 poll(); setInterval(poll, 1000);
-loadTraj(); setInterval(loadTraj, 60000);
 </script>
 </body></html>
 """
@@ -415,14 +354,10 @@ class Dashboard:
         provider: AnalysisNotificationProvider,
         host: str = "127.0.0.1",
         port: int = 0,
-        records_dir: str | Path | None = None,
     ):
         self.provider = provider
         self.host = host
         self.port = port
-        # Perf-records dir backing /api/trajectory (None -> the default
-        # benchmarks/records, resolved against cwd at request time).
-        self.records_dir = records_dir
         self._server = None
         self._thread: threading.Thread | None = None
 
@@ -434,7 +369,6 @@ class Dashboard:
         from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
         provider = self.provider
-        records_dir = self.records_dir
 
         class Handler(BaseHTTPRequestHandler):
             daemon_threads = True
@@ -469,14 +403,6 @@ class Dashboard:
                     since = int(q.get("since", ["0"])[0] or 0)
                     cursor, events = provider.events_since(since)
                     self._json({"next": cursor, "events": events})
-                elif u.path == "/api/trajectory":
-                    q = parse_qs(u.query)
-                    self._json(trajectory_payload(
-                        records_dir,
-                        metric=q.get("metric", ["tok_s"])[0] or "tok_s",
-                        mode=q.get("mode", [""])[0] or None,
-                        benchmark=q.get("benchmark", [""])[0] or None,
-                    ))
                 elif u.path == "/api/stream":
                     self.send_response(200)
                     self.send_header("Content-Type", "text/event-stream")
@@ -515,14 +441,13 @@ def serve_journal(
     follow: bool = True,
     poll_s: float = 0.5,
     total: int | None = None,
-    records_dir: str | Path | None = None,
 ) -> tuple[Dashboard, AnalysisNotificationProvider]:
     """Dashboard over an existing journal file: replay what's there, then
     (with ``follow``) keep tailing it — how you watch a run owned by another
     process, or a whole fleet appending to one shared journal."""
     prov = AnalysisNotificationProvider(total=total)
     offset = prov.replay_journal(journal)
-    dash = Dashboard(prov, host=host, port=port, records_dir=records_dir)
+    dash = Dashboard(prov, host=host, port=port)
     dash.start()
     if follow:
         def tail() -> None:

@@ -12,8 +12,8 @@ Axes/settings understood by :func:`serve_sweep`:
   arch (required)        registry name, e.g. "llama3.2-3b"
   attn_backend           "xla" | "pallas" (default: the config's own)
   n_slots, cache_len     scheduler shape (defaults 4, 128)
-  paged, page_size,      page-pool knobs (defaults True, 16, capacity parity)
-  n_pages, prefill_buckets
+  page_size, n_pages,    page-pool knobs (defaults 16, capacity parity)
+  prefill_buckets
   chunk_budget           unified token-budget step: per-step tokens shared by
                          decode rows + a prefill chunk (None/0 -> whole-prompt
                          prefill at admission)
@@ -141,7 +141,7 @@ def serve_matrix(
     """Build the (arch x attn_backend x scheduler-knob) ConfigMatrix.
 
     ``scheduler`` maps extra axis names to value lists (e.g.
-    ``{"paged": [True, False]}``); ``workload`` keys become matrix settings.
+    ``{"chunk_budget": [0, 64]}``); ``workload`` keys become matrix settings.
     The result is a plain ConfigMatrix — compose with ``+``/``*``/``where``.
     """
     from repro.core.matrix import ConfigMatrix
@@ -241,7 +241,6 @@ def serve_sweep(ctx: Context) -> dict[str, Any]:
     sched_cfg = SchedulerConfig(
         n_slots=int(_opt(ctx, "n_slots", 4)),
         cache_len=int(_opt(ctx, "cache_len", 128)),
-        paged=bool(_opt(ctx, "paged", True)),
         page_size=int(_opt(ctx, "page_size", 16)),
         n_pages=_opt(ctx, "n_pages", None),
         prefill_buckets=bool(_opt(ctx, "prefill_buckets", True)),
@@ -487,7 +486,7 @@ def serve_sweep(ctx: Context) -> dict[str, Any]:
         "mesh_devices": sched.sctx.device_count(),
         "predicted_step_ms": pred.step_time_lower_bound * 1e3 if pred else None,
         "predicted_bottleneck": pred.bottleneck if pred else None,
-        "paged": sched_cfg.paged,
+        "paged": sched.mem.paged,
         "chunk_budget": sched_cfg.chunk_budget,
         "preemption": sched_cfg.preemption,
         "prefix_sharing": sched_cfg.prefix_sharing,

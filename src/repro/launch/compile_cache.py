@@ -1,11 +1,11 @@
 """JAX's persistent compilation cache, for entry points that run on a chip.
 
-Entry points (``chip_smoke.py``, ``benchmarks/run.py``) call
-:func:`enable_compile_cache` once at startup; importing the library never
-turns the cache on. A cache that moves never hits, so the default location
-is fixed: ``<checkout>/.jax_cache``, never a temporary, per-process or
-time-stamped directory. ``JAX_COMPILATION_CACHE_DIR``, when set, wins and
-is left alone (JAX reads it itself).
+The entry point ``chip_smoke.py`` calls :func:`enable_compile_cache` once
+at startup; importing the library never turns the cache on. A cache that
+moves never hits, so the default location is fixed: ``<checkout>/.jax_cache``,
+never a temporary, per-process or time-stamped directory.
+``JAX_COMPILATION_CACHE_DIR``, when set, wins and is left alone (JAX reads
+it itself).
 """
 from __future__ import annotations
 

@@ -36,7 +36,6 @@ class ServeConfig:
     # Scheduler pass-through: paged KV pool + bucketed prefill + unified
     # token-budget step (the static reference path ignores these — it
     # always runs contiguous rows with whole-prompt prefill).
-    paged: bool = True
     page_size: int = 16
     prefill_buckets: bool = True
     n_pages: int | None = None
@@ -89,7 +88,7 @@ class Engine:
                 self.cfg, self.params, self.sctx,
                 SchedulerConfig(
                     n_slots=n_slots, cache_len=self.serve.cache_len,
-                    seed=self.serve.seed, paged=self.serve.paged,
+                    seed=self.serve.seed,
                     page_size=self.serve.page_size,
                     n_pages=self.serve.n_pages,
                     prefill_buckets=self.serve.prefill_buckets,

@@ -60,8 +60,8 @@ class SchedulerConfig:
     cache_len: int = 256  # per-slot logical cache slots
     seed: int = 0
     keep_finished: int = 1024  # finished RequestStates retained for result()
-    # Paged KV pool; n_pages=None sizes it at contiguous capacity parity.
-    paged: bool = True
+    # Paged KV pool, for models whose layers page (model_page_span > 0);
+    # n_pages=None sizes it at contiguous capacity parity.
     page_size: int = 16  # tokens per page
     n_pages: int | None = None
     # Pow2 prompt buckets: prefill/admit compile once per bucket
@@ -144,7 +144,7 @@ class Scheduler:
                 sched.drafter if sched.drafter is not None else NgramDrafter()
             )
 
-        span = model_page_span(cfg, sched.cache_len) if sched.paged else 0
+        span = model_page_span(cfg, sched.cache_len)
         self._paged = span > 0
         if self._paged:
             n_pages = (

@@ -1,5 +1,5 @@
-"""repro.analysis: metric extraction, comparison tables, perf trajectory,
-regression policies, the event-fed dashboard, and the CLI."""
+"""repro.analysis: metric extraction, comparison tables, the event-fed
+dashboard, and the CLI."""
 import json
 import os
 import subprocess
@@ -11,19 +11,14 @@ import pytest
 
 from repro.analysis import (
     AnalysisNotificationProvider,
-    BenchRecord,
     Dashboard,
     Examiner,
     MetricFrame,
     MetricRecord,
     MetricSpec,
-    RegressionPolicy,
-    Trajectory,
     compare,
     compare_frames,
-    detect_regressions,
 )
-from repro.analysis.trajectory import find_baseline
 from repro.core import ConfigMatrix, FileQueue, Memento, RunnerConfig
 from repro.core.notifications import Event
 
@@ -252,132 +247,6 @@ class TestTables:
         assert t.cells == [[1.0, 3.0]]
 
 
-def _write_record(d: Path, n: int, mode: str, commit: str, rows):
-    d.mkdir(parents=True, exist_ok=True)
-    (d / f"BENCH_{n}.json").write_text(json.dumps({
-        "schema": 1, "record": n, "mode": mode, "git_commit": commit,
-        "timestamp": f"2026-08-0{min(n, 9)}T00:00:00+00:00", "rows": rows,
-    }))
-
-
-class TestTrajectory:
-    def test_load_filter_series(self, tmp_path):
-        _write_record(tmp_path, 1, "smoke", "c1",
-                      [{"name": "B9", "tok_s": 10.0}, {"name": "B10", "tok_s": 5.0}])
-        _write_record(tmp_path, 2, "full", "c2", [{"name": "B9", "tok_s": 50.0}])
-        _write_record(tmp_path, 3, "smoke", "c3", [{"name": "B9", "tok_s": 12.0}])
-        traj = Trajectory.load(tmp_path)
-        assert [r.record for r in traj] == [1, 2, 3]
-        assert traj.modes() == ["smoke", "full"]
-        assert traj.filter(mode="smoke").series("B9") == [(1, 10.0), (3, 12.0)]
-        assert traj.latest("smoke").record == 3
-        assert traj.filter(benchmark="B10").names() == ["B10"]
-        frame = traj.to_frame()
-        assert frame.where(benchmark="B9", mode="smoke").values() == [10.0, 12.0]
-
-    def test_half_written_and_foreign_files_skipped(self, tmp_path):
-        _write_record(tmp_path, 1, "smoke", "c1", [{"name": "B9", "tok_s": 1.0}])
-        (tmp_path / "BENCH_2.json").write_text("{ truncated")
-        (tmp_path / "BENCH_x.json").write_text("{}")
-        assert len(Trajectory.load(tmp_path)) == 1
-
-    def test_find_baseline_prefers_lineage_ancestor(self, tmp_path):
-        # record 2 is on a diverged branch; record 1 is an ancestor.
-        _write_record(tmp_path, 1, "smoke", "main1", [])
-        _write_record(tmp_path, 2, "smoke", "branch", [])
-        _write_record(tmp_path, 3, "smoke", "main2", [])
-        traj = Trajectory.load(tmp_path)
-        lineage = {("main1", "main2"): True, ("branch", "main2"): False}
-        base = find_baseline(traj, traj.get(3),
-                             is_ancestor=lambda o, n: lineage[(o, n)])
-        assert base.record == 1
-
-    def test_find_baseline_fallback_when_lineage_unknowable(self, tmp_path):
-        _write_record(tmp_path, 1, "smoke", "a", [])
-        _write_record(tmp_path, 2, "smoke", "b", [])
-        traj = Trajectory.load(tmp_path)
-        base = find_baseline(traj, traj.get(2), is_ancestor=lambda o, n: None)
-        assert base.record == 1
-
-    def test_find_baseline_none_when_all_diverged_or_other_mode(self, tmp_path):
-        _write_record(tmp_path, 1, "full", "a", [])
-        _write_record(tmp_path, 2, "smoke", "b", [])
-        _write_record(tmp_path, 3, "smoke", "c", [])
-        traj = Trajectory.load(tmp_path)
-        assert find_baseline(traj, traj.get(3),
-                             is_ancestor=lambda o, n: False) is None
-
-    def test_detect_regressions_policy_and_skips(self, tmp_path):
-        base_rows = [
-            {"name": "B9", "tok_s": 100.0},
-            {"name": "B10"},  # no tok_s on the baseline: must be skipped
-            {"name": "B11", "tok_s": 0.0},  # zero baseline: skipped
-            {"name": "B12", "tok_s": 10.0, "itl_ms": 4.0},
-        ]
-        new_rows = [
-            {"name": "B9", "tok_s": 60.0},  # 0.60x -> flagged
-            {"name": "B10", "tok_s": 1.0},
-            {"name": "B11", "tok_s": 5.0},
-            {"name": "B12", "tok_s": 9.0, "itl_ms": 9.0},  # itl worse 2.25x
-        ]
-        _write_record(tmp_path, 1, "smoke", "c1", base_rows)
-        _write_record(tmp_path, 2, "smoke", "c1", new_rows)
-        traj = Trajectory.load(tmp_path)
-        regs = detect_regressions(traj.get(2), traj.get(1))
-        assert [r.name for r in regs] == ["B9"]
-        assert regs[0].warn_line() == (
-            "WARN,B9,tok/s 100.0 -> 60.0 (0.60x vs record 1, >30% regression)"
-        )
-        both = detect_regressions(
-            traj.get(2), traj.get(1),
-            policies=(RegressionPolicy(),
-                      RegressionPolicy("itl_ms", max_drop=0.5,
-                                       higher_is_better=False)),
-        )
-        assert {(r.name, r.metric) for r in both} == {("B9", "tok_s"),
-                                                      ("B12", "itl_ms")}
-
-    def test_run_py_diff_delegates_and_matches_cli(self, tmp_path):
-        """The harness's WARN lines and the CLI's are identical verdicts."""
-        _write_record(tmp_path, 1, "smoke", "unknown",
-                      [{"name": "B9", "tok_s": 100.0},
-                       {"name": "B10"}])
-        _write_record(tmp_path, 2, "smoke", "unknown",
-                      [{"name": "B9", "tok_s": 50.0},
-                       {"name": "B10", "tok_s": 9.9}])
-        import importlib.util
-
-        run_path = Path(__file__).parent.parent / "benchmarks" / "run.py"
-        spec = importlib.util.spec_from_file_location("bench_run", run_path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        harness_lines = mod.diff_records(
-            str(tmp_path / "BENCH_2.json"), str(tmp_path)
-        )
-        out = _cli("regressions", "--records-dir", str(tmp_path))
-        cli_lines = [ln for ln in out.stdout.splitlines()
-                     if ln.startswith("WARN,")]
-        assert harness_lines == cli_lines == [
-            "WARN,B9,tok/s 100.0 -> 50.0 (0.50x vs record 1, >30% regression)"
-        ]
-
-    def test_cli_regressions_strict_gates(self, tmp_path):
-        _write_record(tmp_path, 1, "smoke", "unknown",
-                      [{"name": "B9", "tok_s": 100.0}])
-        _write_record(tmp_path, 2, "smoke", "unknown",
-                      [{"name": "B9", "tok_s": 50.0}])
-        assert _cli("regressions", "--records-dir", str(tmp_path)).returncode == 0
-        strict = _cli("regressions", "--records-dir", str(tmp_path), "--strict")
-        assert strict.returncode == 1
-        # no regression -> strict passes
-        _write_record(tmp_path / "ok", 1, "smoke", "unknown",
-                      [{"name": "B9", "tok_s": 100.0}])
-        _write_record(tmp_path / "ok", 2, "smoke", "unknown",
-                      [{"name": "B9", "tok_s": 95.0}])
-        assert _cli("regressions", "--records-dir", str(tmp_path / "ok"),
-                    "--strict").returncode == 0
-
-
 def _event(kind, t=1000.0, **payload):
     return Event(kind=kind, message="", unix_time=t, payload=payload)
 
@@ -457,50 +326,6 @@ class TestDashboardProvider:
         assert len(results) == 3
         assert prov.state()["done"] == 3  # not 6
 
-    def test_trajectory_payload(self, tmp_path):
-        from repro.analysis.dash import trajectory_payload
-
-        _write_record(tmp_path, 1, "smoke", "c1",
-                      [{"name": "B9", "tok_s": 10.0, "wall_s": 2.0},
-                       {"name": "B10", "tok_s": 5.0}])
-        _write_record(tmp_path, 2, "smoke", "c2",
-                      [{"name": "B9", "tok_s": 12.0, "wall_s": 1.5}])
-        t = trajectory_payload(tmp_path)
-        assert t["metric"] == "tok_s" and t["records"] == [1, 2]
-        assert t["series"]["B9"] == [{"record": 1, "value": 10.0},
-                                     {"record": 2, "value": 12.0}]
-        assert t["series"]["B10"] == [{"record": 1, "value": 5.0}]
-        # metric/benchmark filters
-        t = trajectory_payload(tmp_path, metric="wall_s", benchmark="B9")
-        assert list(t["series"]) == ["B9"]
-        assert [p["value"] for p in t["series"]["B9"]] == [2.0, 1.5]
-        # empty dir: valid empty payload, not an error
-        assert trajectory_payload(tmp_path / "none")["series"] == {}
-
-    def test_http_trajectory_endpoint(self, tmp_path):
-        _write_record(tmp_path, 1, "smoke", "c1",
-                      [{"name": "B9", "tok_s": 10.0}])
-        _write_record(tmp_path, 2, "smoke", "c2",
-                      [{"name": "B9", "tok_s": 12.0}])
-        dash = Dashboard(AnalysisNotificationProvider(),
-                         records_dir=tmp_path)
-        url = dash.start()
-        try:
-            with urllib.request.urlopen(f"{url}/api/trajectory",
-                                        timeout=5) as r:
-                t = json.loads(r.read())
-            assert t["series"]["B9"] == [{"record": 1, "value": 10.0},
-                                         {"record": 2, "value": 12.0}]
-            with urllib.request.urlopen(
-                f"{url}/api/trajectory?benchmark=B99", timeout=5
-            ) as r:
-                assert json.loads(r.read())["series"] == {}
-            with urllib.request.urlopen(url, timeout=5) as r:
-                page = r.read().decode()
-            assert "/api/trajectory" in page and "spark" in page
-        finally:
-            dash.stop()
-
     def test_http_endpoints(self):
         prov = AnalysisNotificationProvider()
         self._feed(prov)
@@ -537,50 +362,17 @@ class TestCLI:
         assert out.returncode == 0, out.stderr
         assert out.stdout.strip() == api
 
-    def test_table_latest_record(self, tmp_path):
-        _write_record(tmp_path, 1, "smoke", "c1",
-                      [{"name": "B9", "tok_s": 10.0}])
-        out = _cli("table", "--latest", "--records-dir", str(tmp_path))
-        assert out.returncode == 0, out.stderr
-        assert "| B9 | 10 |" in out.stdout
-        assert "Benchmark record 1" in out.stdout
-
-    def test_table_cli_diff_records(self, tmp_path):
-        _write_record(tmp_path, 1, "smoke", "c1",
-                      [{"name": "B9", "tok_s": 100.0},
-                       {"name": "B10", "tok_s": 8.0}])
-        _write_record(tmp_path, 2, "smoke", "c2",
-                      [{"name": "B9", "tok_s": 50.0}])
-        out = _cli("table", "--diff", "1", "2",
-                   "--records-dir", str(tmp_path))
-        assert out.returncode == 0, out.stderr
-        assert "record 2 (vs record 1)" in out.stdout
-        assert "(0.50x, -50.0%)" in out.stdout
-        assert "| B10 | 8 | - |" in out.stdout
-        # identical to the API, token for token
-        traj = Trajectory.load(tmp_path)
-        api = compare_frames(
-            [(f"record {n}", Trajectory([traj.get(n)]).to_frame())
-             for n in (1, 2)],
-            rows="benchmark", metric="tok_s",
-            title="tok_s: record 1 vs record 2",
-        ).to_markdown()
-        assert out.stdout.strip() == api
-
     def test_table_cli_diff_errors(self, tmp_path):
-        _write_record(tmp_path, 1, "smoke", "c1",
-                      [{"name": "B9", "tok_s": 1.0}])
-        out = _cli("table", "--diff", "1", "7",
-                   "--records-dir", str(tmp_path))
+        a = tmp_path / "a.csv"
+        _run_sweep().to_csv(a)
+        out = _cli("table", "--diff", str(a), str(tmp_path / "gone.csv"),
+                   "--rows", "n", "--metric", "tokens_per_s")
         assert out.returncode != 0
-        assert "no record 7" in out.stderr
-        out = _cli("table", "--diff", "1", "--records-dir", str(tmp_path))
+        assert "no results CSV" in out.stderr and "gone.csv" in out.stderr
+        out = _cli("table", "--diff", str(a), "--rows", "n",
+                   "--metric", "tokens_per_s")
         assert out.returncode != 0
         assert "at least two" in out.stderr
-        out = _cli("table", "--diff", "1", "1", "--latest",
-                   "--records-dir", str(tmp_path))
-        assert out.returncode != 0
-        assert "exclusive" in out.stderr
 
     def test_table_cli_diff_csv_runs(self, tmp_path):
         res = _run_sweep()
@@ -596,18 +388,6 @@ class TestCLI:
         # identical inputs: every diff column is exactly 1.00x
         assert "(1.00x, +0.0%)" in out.stdout
         assert "(vs " in out.stdout
-
-    def test_trajectory_cli_json(self, tmp_path):
-        _write_record(tmp_path, 1, "smoke", "c1",
-                      [{"name": "B9", "tok_s": 10.0}])
-        _write_record(tmp_path, 2, "smoke", "c2",
-                      [{"name": "B9", "tok_s": 12.0}])
-        out = _cli("trajectory", "--records-dir", str(tmp_path),
-                   "--series", "B9", "--json")
-        assert out.returncode == 0, out.stderr
-        data = json.loads(out.stdout)
-        assert data["series"] == [{"record": 1, "value": 10.0},
-                                  {"record": 2, "value": 12.0}]
 
     def test_filequeue_stats_json(self, tmp_path):
         q = FileQueue(tmp_path / "q", owner="me")

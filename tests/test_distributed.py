@@ -1,4 +1,4 @@
-"""Distributed sweep runtime: file-queue race regressions + multi-process
+"""Distributed sweep runtime: file-queue race tests + multi-process
 contention/crash-recovery suite.
 
 The four deterministic regression tests interleave the historical races by

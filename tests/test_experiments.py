@@ -72,11 +72,11 @@ class TestServeSweep:
 
     def test_sweep_composes_with_matrix_algebra(self):
         m = serve_matrix(ARCHS, backends=BACKENDS, n_requests=2) * {
-            "parameters": {"paged": [True, False]}
+            "parameters": {"prefix_sharing": [True, False]}
         }
         tasks = m.task_list()
         assert len(tasks) == 8
-        assert {t.params["paged"] for t in tasks} == {True, False}
+        assert {t.params["prefix_sharing"] for t in tasks} == {True, False}
 
     def test_serve_sweep_distributed_across_hosts(self, tmp_path):
         """The ROADMAP item: a serve sweep drained through the file-queue.

@@ -42,6 +42,8 @@ class TestChunkedTokenIdentity:
         "arch",
         [
             "llama3.2-3b",  # dense GQA, paged
+            "qwen3-8b",  # dense GQA, per-head q/k RMSNorm
+            "qwen2.5-14b",  # dense GQA, q/k/v bias
             "recurrentgemma-2b",  # windowed ring KV + RG-LRU hybrid
             "deepseek-v2-236b",  # MLA compressed cache (per-slot path)
             "xlstm-1.3b",  # pure recurrent (mLSTM + sLSTM), zero pages

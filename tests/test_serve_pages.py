@@ -1,6 +1,7 @@
 """Paged KV serving: PagePool alloc/free invariants (property tests),
 paged-vs-static greedy token identity across the model zoo's state
-families, pool-capacity admission backpressure, shape-stable decode under
+families, the cache layout each registered arch gets by default,
+pool-capacity admission backpressure, shape-stable decode under
 page growth, bucketed-prefill compile counts, and the paged decode kernel
 vs its XLA gather reference."""
 import json
@@ -18,7 +19,7 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.configs.registry import get_config
+from repro.configs.registry import get_config, list_archs
 from repro.kernels import decode_attention as dec
 from repro.kernels import ops as kops
 from repro.models import lm
@@ -298,6 +299,8 @@ class TestPagedTokenIdentity:
         "arch",
         [
             "llama3.2-3b",  # dense GQA
+            "qwen3-8b",  # dense GQA, per-head q/k RMSNorm
+            "qwen2.5-14b",  # dense GQA, q/k/v bias
             "recurrentgemma-2b",  # windowed ring KV + recurrent hybrid
             "deepseek-v2-236b",  # MLA (per-slot path behind same interface)
             "xlstm-1.3b",  # pure recurrent: zero pages
@@ -317,22 +320,6 @@ class TestPagedTokenIdentity:
             eng.generate(batch).tokens, eng.generate_static(batch).tokens
         )
 
-    def test_paged_matches_contiguous_scheduler(self):
-        """Same requests through a paged and a contiguous scheduler produce
-        identical greedy tokens (the pool is an invisible layout change)."""
-        cfg, params = _params_for("llama3.2-3b")
-        prompts = _prompts(cfg, [5, 11, 7, 9], seed=2)
-        outs = []
-        for paged in (True, False):
-            sched = Scheduler(
-                cfg, params, ShardingCtx.null(),
-                SchedulerConfig(n_slots=2, cache_len=64, paged=paged, page_size=8),
-            )
-            for p in prompts:
-                sched.submit(Request(p, max_new_tokens=6))
-            outs.append([rs.tokens for rs in sched.run()])
-        assert outs[0] == outs[1]
-
     def test_ring_window_prompt_longer_than_window_paged(self):
         """Windowed arch, prompt > window: ring-folded pages match static."""
         cfg, params = _params_for("recurrentgemma-2b")
@@ -347,6 +334,33 @@ class TestPagedTokenIdentity:
         np.testing.assert_array_equal(
             eng.generate(batch).tokens, eng.generate_static(batch).tokens
         )
+
+
+# ==========================================================================
+# The cache layout follows the model
+# ==========================================================================
+@pytest.mark.parametrize("arch", list_archs())
+def test_layout_follows_the_model(arch):
+    """No option picks the cache layout: a Scheduler built with defaults
+    holds a page table and page-pool leaves exactly when the model's
+    layers page, and reports page bytes exactly then."""
+    cfg, params = _params_for(arch)
+    n_slots, cache_len, page = 2, 64, 8
+    span = model_page_span(cfg, cache_len)
+    sched = Scheduler(
+        cfg, params, ShardingCtx.null(),
+        SchedulerConfig(n_slots=n_slots, cache_len=cache_len, page_size=page),
+    )
+    state = sched._states
+    n_pages = n_slots * -(-span // page)  # capacity parity
+    pools = [
+        x for x in jax.tree.leaves(state["layers"])
+        if x.ndim >= 4 and x.shape[-4:-2] == (n_pages + 1, page)
+    ]
+    assert ("page_table" in state) == (span > 0)
+    assert bool(pools) == (span > 0)
+    assert sched.mem.paged == (span > 0)
+    assert (set(sched.paged_cache_bytes().values()) == {0}) == (span == 0)
 
 
 # ==========================================================================
@@ -394,13 +408,13 @@ class TestPoolBackpressure:
             sched.run()
 
     def test_zero_page_models_skip_pool(self):
-        """Pure-recurrent models need no pages; the paged config degrades to
-        the per-slot path with no pool at all."""
+        """Pure-recurrent models need no pages; the scheduler takes the
+        per-slot path with no pool at all."""
         cfg, params = _params_for("xlstm-1.3b")
         assert model_page_span(cfg, 64) == 0
         sched = Scheduler(
             cfg, params, ShardingCtx.null(),
-            SchedulerConfig(n_slots=2, cache_len=64, paged=True),
+            SchedulerConfig(n_slots=2, cache_len=64),
         )
         assert sched.pool is None
         sched.submit(Request(_prompts(cfg, [6])[0], max_new_tokens=3))

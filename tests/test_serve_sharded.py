@@ -4,16 +4,15 @@
 Greedy token identity sharded vs unsharded across the model zoo's state
 families, layout-aware hot-path features (CoW forks, preemption swap and
 recompute round-trips, speculative rollback) with pool conservation under
-a 2-device mesh, bounded compile counts independent of mesh size, the
-analytic decode roofline predictor, and the policy-file regression gate.
+a 2-device mesh, bounded compile counts independent of mesh size, and the
+analytic decode roofline predictor.
 
 Multi-device cases gate on ``mesh.devices_required(2)`` and *skip* on
 1-device CI; the sharded-smoke CI lane forces 8 host devices
 (``XLA_FLAGS=--xla_force_host_platform_device_count=8``) so they run for
-real there. Everything mesh-independent (predictor math, policy loading,
-mesh error messages) runs everywhere.
+real there. Everything mesh-independent (predictor math, mesh error
+messages) runs everywhere.
 """
-import json
 
 import jax
 import numpy as np
@@ -474,52 +473,3 @@ class TestDecodeRoofline:
         ) == pytest.approx(2.0)
         assert spec.from_row({"itl_p50_s": 0.002, "predicted_step_ms": 0}) is None
         assert spec.from_row({"predicted_step_ms": 1.0}) is None
-
-
-# ==========================================================================
-# Policy-file regression gate
-# ==========================================================================
-class TestPolicyFile:
-    def test_load_policies_roundtrip(self, tmp_path):
-        from repro.analysis.trajectory import RegressionPolicy, load_policies
-
-        p = tmp_path / "policy.json"
-        p.write_text(json.dumps({
-            "policies": [
-                {"metric": "tok_s", "max_drop": 0.25, "label": "tok/s"},
-                {"metric": "itl_p50_ms", "max_drop": 0.5,
-                 "higher_is_better": False},
-            ]
-        }))
-        pols = load_policies(p)
-        assert pols == (
-            RegressionPolicy(metric="tok_s", max_drop=0.25, label="tok/s"),
-            RegressionPolicy(
-                metric="itl_p50_ms", max_drop=0.5, higher_is_better=False
-            ),
-        )
-
-    def test_load_policies_missing_file_falls_back(self, tmp_path):
-        from repro.analysis.trajectory import DEFAULT_POLICIES, load_policies
-
-        assert load_policies(tmp_path / "nope.json") == DEFAULT_POLICIES
-
-    def test_load_policies_malformed_raises(self, tmp_path):
-        from repro.analysis.trajectory import load_policies
-
-        p = tmp_path / "policy.json"
-        p.write_text(json.dumps({"policies": [{"metrik": "tok_s"}]}))
-        with pytest.raises(ValueError, match="unknown policy fields"):
-            load_policies(p)
-
-    def test_checked_in_policy_file_loads(self):
-        import os
-
-        from repro.analysis.trajectory import load_policies
-
-        path = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "benchmarks", "policy.json",
-        )
-        pols = load_policies(path)
-        assert any(p.metric == "tok_s" for p in pols)
