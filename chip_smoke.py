@@ -157,20 +157,23 @@ def forced_logits(cfg, *, backend: str, mesh_shape=None, forced=None):
         0, cfg.vocab_size, size=PROMPT_LENS[0]
     ).astype(np.int32)
     sched.submit(Request(prompt, max_new_tokens=DECODE_STEPS + 1))
-    # Admit and stream the prompt (one chunk) without the decode step that
-    # sched.step() would run next, so the first decode input can be forced.
+    # Admit and stream the prompt (one chunk), then dispatch one decode step
+    # at a time and read it before the next, rather than sched.step(), which
+    # queues the next step ahead: each decode input can then be forced.
     sched._admit_pending()
     sched._prefill_chunk_step()
-    (rs,) = sched._active.values()
+    ((_, slot, tokens, last),) = sched._ev["firsts"]
     V = cfg.vocab_size
-    logits = [np.asarray(rs.prefill_logits, np.float32).reshape(-1)[:V]]
+    logits = [np.asarray(last, np.float32).reshape(-1)[:V]]
     fed = []
     for i in range(DECODE_STEPS):
-        if forced is not None:
-            sched._tokens[rs.slot, 0] = forced[i]
-        fed.append(int(sched._tokens[rs.slot, 0]))
-        sched.step()
-        logits.append(np.asarray(sched.last_decode_logits[rs.slot, -1, :V], np.float32))
+        tok = int(forced[i]) if forced is not None else int(np.asarray(tokens)[slot, 0])
+        fed.append(tok)
+        sched._place(slot, tok)
+        sched._dispatch()
+        step = sched._flights.pop()
+        tokens = step["toks"]
+        logits.append(np.asarray(step["logits"][slot, -1, :V], np.float32))
     devices = sctx.mesh.devices.flat if sctx.mesh is not None else jax.devices()[:1]
     in_use = [(d.memory_stats() or {}).get("bytes_in_use", 0) for d in devices]
     del sched, params

@@ -75,8 +75,14 @@ def admit_pending(s) -> None:
         s._charge_tenant(rs)
 
 
+def streams(s, rs: RequestState) -> bool:
+    """Whether ``rs`` is admitted by streaming its prompt in chunks (else
+    by whole-prompt prefill: modality prefixes, enc-dec, no chunking)."""
+    return s._stream_capable and not rs.request.extras
+
+
 def admit(s, rs: RequestState) -> bool:
-    if s._stream_capable and not rs.request.extras:
+    if streams(s, rs):
         return admit_streaming(s, rs)
     return admit_prefill(s, rs)
 
@@ -204,7 +210,7 @@ def try_resume(s, rs: RequestState) -> bool:
     s._slot_worst[slot] = (rs.request.tenant, s._worst_pages(rs))
     rs.status = RequestStatus.ACTIVE
     rs.t_admit = time.perf_counter()
-    s._tokens[slot, 0] = rs.tokens[-1]
+    s._place(slot, rs.tokens[-1])
     s._temps[slot] = rs.request.temperature
     s._active_mask[slot] = True
     s._active[slot] = rs
@@ -264,28 +270,21 @@ def admit_prefill(s, rs: RequestState) -> bool:
 
     rs.t_admit = time.perf_counter()
     s._key, sub = jax.random.split(s._key)
+    s._tok = s.programs.sample(
+        logits[:, -1, :], jnp.full((1,), req.temperature, jnp.float32), sub,
+        s._tok, s._put(np.array([slot], np.int32)),
+    )
     with s._phase("serve.wait.first_token", rid=rs.rid):
-        first = int(
-            np.asarray(
-                s.programs.sample(
-                    logits[:, -1, :], jnp.full((1,), req.temperature, jnp.float32), sub
-                )
-            )[0]
-        )
+        vec, rs.prefill_logits = jax.device_get((s._tok, logits[:, -1:, :]))
         now = time.perf_counter()  # the first token is on the host
-        rs.prefill_logits = np.asarray(logits[:, -1:, :])
     rs.slot = slot
     rs.prompt_len = prompt_len
     rs.status = RequestStatus.ACTIVE
-    rs.tokens = [first]
-    rs.t_first_token = now
-    rs.t_tokens.append(now)
-    s._tokens[slot, 0] = first
     s._temps[slot] = req.temperature
     s._active_mask[slot] = True
     s._active[slot] = rs
     s._ev["admits"].append(planlib.AdmitPlan(rs.rid, "prefill", slot, n_reserve))
     # A 1-token request (or an immediate stop) retires before ever riding
     # the decode step, freeing the slot for this admission loop.
-    s._maybe_finish(rs, now)
+    s._emit(rs, int(vec[slot, 0]), now)
     return True

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.serve import plan as planlib
+from repro.serve import spec_exec
 from repro.serve.request import RequestState, RequestStatus
 
 
@@ -89,35 +90,36 @@ def prefill_chunk_step(s) -> bool:
 
 
 def finish_prefill(s, rs: RequestState, logits: jax.Array) -> None:
-    """The prompt is fully streamed: join the decode batch. The first
-    token is stamped once it has reached the host."""
+    """The prompt is fully streamed: join the decode batch. The first token
+    is sampled into the slot's next decode input on the device and read
+    with the step's other tokens. Where the host may need it before then
+    it is read at once: the step drafts from it (speculation), or page
+    growth may preempt the slot (reservation-free admission), and a
+    victim's tokens must all be on the host. It is stamped once it has
+    reached the host."""
     slot = rs.slot
     req = rs.request
+    rs.status = RequestStatus.ACTIVE
+    s._temps[slot] = req.temperature
+    s._active_mask[slot] = True
     if rs.replay_tokens is not None:
         # Recompute resume: the last generated token was never fed back; it
         # is the next decode input, not a fresh sample.
         rs.replay_tokens = None
-        s._tokens[slot, 0] = rs.tokens[-1]
-        now = time.perf_counter()
-    else:
-        s._key, sub = jax.random.split(s._key)
-        with s._phase("serve.wait.first_token", rid=rs.rid):
-            first = int(
-                np.asarray(
-                    s.programs.sample(
-                        logits[:, -1, :],
-                        jnp.full((1,), req.temperature, jnp.float32),
-                        sub,
-                    )
-                )[0]
-            )
-            now = time.perf_counter()  # the first token is on the host
-            rs.prefill_logits = np.asarray(logits[:, -1:, :])
-        rs.tokens = [first]
-        rs.t_first_token = now
-        rs.t_tokens.append(now)
-        s._tokens[slot, 0] = first
-    rs.status = RequestStatus.ACTIVE
-    s._temps[slot] = req.temperature
-    s._active_mask[slot] = True
-    s._maybe_finish(rs, now)
+        s._place(slot, rs.tokens[-1])
+        return
+    s._key, sub = jax.random.split(s._key)
+    s._tok = s.programs.sample(
+        logits[:, -1, :], jnp.full((1,), req.temperature, jnp.float32), sub,
+        s._tok, s._put(np.array([slot], np.int32)),
+    )
+    s._queue_token(rs)
+    first = (rs, slot, s._tok, logits[:, -1:, :])
+    if s.sched.preemption == "off" and not (s._spec and spec_exec.drafts(s, rs)):
+        s._ev["firsts"].append(first)
+        return
+    with s._phase("serve.wait.first_token", rid=rs.rid):
+        vec, rs.prefill_logits = jax.device_get(first[2:])
+        now = time.perf_counter()  # the first token is on the host
+    rs.queued -= 1
+    s._emit(rs, int(vec[slot, 0]), now)

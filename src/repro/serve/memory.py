@@ -231,6 +231,11 @@ class MemoryManager:
             )
         return len(removed)
 
+    def unmap(self, slot: int) -> None:
+        """Point ``slot``'s table row at trash but keep its pages: the slot
+        decodes no more, and releases them once its last token is read."""
+        self.pt[slot, :] = self.trash_of(slot)
+
     def release(self, slot: int) -> None:
         """Free the slot's pages, reservation, mirror row, and prefix
         bookkeeping (indexed pages park in the shard's cached list)."""

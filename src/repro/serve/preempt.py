@@ -31,13 +31,19 @@ def apply_cow(s, forks: list[tuple[int, int, int]]) -> None:
 
 def ensure_pages(s, slot: int, n_total: int, rid: int | None = None) -> bool:
     """Make ``slot``'s reservation cover ``n_total`` pages. Under worst-case
-    reservations this always holds; reservation-free, extend incrementally
-    and reclaim victims' pages (within the slot's data shard) until it can
-    be backed."""
+    reservations this always holds; reservation-free, extend incrementally,
+    first completing the step in flight (its finishing requests free pages),
+    then reclaiming victims' pages (within the slot's data shard), until it
+    can be backed. False also when the slot's own request finished as the
+    step in flight completed."""
     if s.sched.preemption == "off":
         return True  # admission reserved the worst case
     shard = s.mem.shard_of(slot) if s.mem.data_shards > 1 else None
     while not s.mem.extend_to(slot, n_total):
+        if s._drain():
+            if slot not in s._active:
+                return False
+            continue
         if not preempt_lru(s, protect=slot, requester_rid=rid, shard=shard):
             return False
     return True
@@ -47,13 +53,18 @@ def grow_pages(s, skip: set[int] = frozenset()) -> None:
     """Allocate the page backing the position each decoding slot writes
     this step — preempting first when reservation-free, including the
     growing slot *itself* when everyone else's pages are pinned."""
-    for slot, rs in list(s._active.items()):
-        if rs.status is not RequestStatus.ACTIVE or slot in skip:
+    for slot in np.flatnonzero(s._active_mask).tolist():
+        # A slot may stop decoding mid-loop: preempted, or finished as the
+        # step in flight completed.
+        if slot in skip or not s._active_mask[slot]:
             continue
+        rs = s._active[slot]
         need = s.mem.pages_for_len(int(s._pos_host[slot]) + 1)
         if need <= s.mem.held(slot):
             continue
         if not ensure_pages(s, slot, need, rid=rs.rid):
+            if not s._active_mask[slot]:
+                continue
             if can_preempt(s, rs):
                 preempt_slot(s, slot)
                 continue
@@ -79,7 +90,9 @@ def preempt_lru(
 ) -> bool:
     """Reclaim a victim's pages: plan-layer pick (least-recently-(re)admitted
     preemptable ACTIVE slot, else a *younger* PREFILLING streamer — see
-    plan.pick_victim). Returns False when none exists."""
+    plan.pick_victim). Returns False when none exists. The step in flight
+    completes first: a victim's tokens must all be on the host."""
+    s._drain()
     views = [
         planlib.SlotView(
             slot=sl, rid=rs.rid,
@@ -102,6 +115,7 @@ def preempt_lru(
 
 def preempt_slot(s, slot: int) -> None:
     rs = s._active[slot]
+    assert not rs.queued, "a preemption victim's tokens must be on the host"
     if rs.status is RequestStatus.PREFILLING:
         # A parked streamer restarts from chunk 0 on resume under either
         # policy; pages it registered in the prefix index survive in the
@@ -124,7 +138,6 @@ def preempt_slot(s, slot: int) -> None:
     s.preemptions_total += 1
     s._ev["preempted"].append(rs.rid)
     s._active_mask[slot] = False
-    s._tokens[slot, 0] = 0
     del s._active[slot]
     heapq.heappush(s._free_slots, slot)
     s.mem.release(slot)

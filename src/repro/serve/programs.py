@@ -3,10 +3,10 @@
 :class:`ProgramRegistry` owns every compiled program the executor runs —
 decode, whole-prompt prefill + admit graft, chunk streaming, speculative
 verify, slot reset, copy-on-write page forks, swap-out/in, position fixup,
-and sampling — together with the two mesh concerns the step path should
-never touch: routing host arrays through fully-replicated ``device_put``
-(:meth:`put`) and pinning program outputs to the profile-resolved
-NamedShardings (:meth:`constrain_layers`).
+and sampling into the slots' next-input token vector — together with the
+two mesh concerns the step path should never touch: routing host arrays
+through fully-replicated ``device_put`` (:meth:`put`) and pinning program
+outputs to the profile-resolved NamedShardings (:meth:`constrain_layers`).
 
 Programs are ``jax.jit`` callables; jit's shape cache keys each one by its
 argument shapes, so a program effectively compiles once per (program,
@@ -447,17 +447,30 @@ class ProgramRegistry:
             self.swap_out = jax.jit(_swap_out_fn)
             self.swap_in = program(_swap_in_fn, 0, 1)
 
-        def _sample_fn(logits, temps, key):
+        def _sample_fn(logits, temps, key, prev, dest):
             # Over a vocabulary split across chips, the choice reduces
-            # across them.
+            # across them. Row i's token lands in slot dest[i] of the slots'
+            # next-input vector; a destination past the last slot (a row
+            # that did not decode) leaves that slot's token as it was.
             with jax.named_scope("head.sample"):
                 lg = logits[:, : cfg.vocab_size].astype(jnp.float32)
                 greedy = jnp.argmax(lg, axis=-1)
                 scaled = lg / jnp.maximum(temps, 1e-6)[:, None]
                 sampled = jax.random.categorical(key, scaled, axis=-1)
-                return jnp.where(temps > 0.0, sampled, greedy).astype(jnp.int32)
+                tok = jnp.where(temps > 0.0, sampled, greedy).astype(jnp.int32)
+                return prev.at[dest, 0].set(tok, mode="drop")
 
-        self.sample = jax.jit(_sample_fn)
+        # The token vector keeps the layout ``put`` gives, so the decode
+        # program meets one input layout whichever program wrote it last.
+        tokens_out = {} if self._replicated is None else {
+            "out_shardings": self._replicated
+        }
+        self.sample = jax.jit(_sample_fn, **tokens_out)
+        # A token the host already knows (a resumed or verified slot's last
+        # one) written into the next-input vector.
+        self.place = jax.jit(
+            lambda prev, slot, tok: prev.at[slot, 0].set(tok), **tokens_out
+        )
 
     # -- sharding glue --------------------------------------------------------
     def put(self, x):

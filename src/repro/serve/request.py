@@ -58,6 +58,7 @@ class RequestState:
     finish_reason: str | None = None  # "stop" | "length"
     prefill_logits: np.ndarray | None = None  # (1, 1, V) last-position logits
     decode_steps: int = 0  # decode iterations this request rode in
+    queued: int = 0  # tokens sampled on the device, not yet read by the host
     # Chunked-prefill cursor: prompt tokens already streamed into the cache
     # (counts teacher-forced replay tokens after a recompute resume).
     chunk_pos: int = 0

@@ -19,6 +19,14 @@ in `phase_time_s`. Semantics are unchanged from the pre-split scheduler
 and pinned by the serve suites: greedy outputs token-identical to
 `generate_static`, one trace per program bucket, inactive slots decode
 garbage into trash pages behind frozen positions.
+
+One step stays queued on the device ahead of the host. The sampled tokens
+never leave the device on their way to the next decode: `sample` writes
+them into the slots' next-input vector (`_tok`), and a `step()` dispatches
+the next step before it reads the tokens of the one before, so the host's
+emit, plan and dispatch overlap the device's work. Plans that need a token
+the device still holds read first (`_reads_first`, and preemption through
+`preempt.ensure_pages`).
 """
 from __future__ import annotations
 
@@ -196,7 +204,9 @@ class Scheduler:
         if self._paged:
             state["page_table"] = self._put(self.mem.pt)
         self._states: dict[str, Any] = state
-        self._tokens = np.zeros((n, 1), np.int32)  # next input token per slot
+        # Each slot's next decode input, on the device: the programs that
+        # sample write it, and no decode input makes a round trip.
+        self._tok = self._put(np.zeros((n, 1), np.int32))
         self._temps = np.zeros((n,), np.float32)
         self._active_mask = np.zeros((n,), bool)
         self._pos_host = np.zeros((n,), np.int64)  # tokens cached per slot
@@ -231,6 +241,10 @@ class Scheduler:
         self._key = jax.random.PRNGKey(sched.seed)
 
         self.total_decode_steps = 0
+        # Decode steps dispatched while the step before was still in flight,
+        # and steps completed before the next plan, which needed their tokens.
+        self.overlapped_steps = 0
+        self.drained_steps = 0
         self.total_chunk_steps = 0
         self.total_spec_steps = 0  # verify calls (one slot each)
         self.total_spec_replays = 0  # partial-accept rollback replays
@@ -256,10 +270,11 @@ class Scheduler:
         if counted:
             self._trash_rows = np.array([self.mem.trash_of(s) for s in range(n)])
         self.phase_time_s: dict[str, float] = {}  # cumulative time per step span
-        self._ev: dict[str, Any] = {
-            "admits": [], "chunk": None, "verifies": [], "rows": (),
-            "preempted": [],
-        }
+        self._ev: dict[str, Any] = self._new_record()
+        # Dispatched steps whose tokens the host has not read, oldest first:
+        # one between steps, two while a step() plans the next.
+        self._flights: deque[dict[str, Any]] = deque()
+        self._done = False  # whether this step() call completed a step
 
     # -- layer glue ----------------------------------------------------------
     def _plan(self, fn, *args, **kw):
@@ -278,7 +293,11 @@ class Scheduler:
         ``serve.admit``, ``serve.chunk``, ``serve.wait.first_token``,
         ``serve.verify`` (holding ``serve.wait.verify``), ``serve.pages``,
         ``serve.decode``, ``serve.wait.decode`` and ``serve.emit``;
-        ``serve.wait.*`` are the blocks on the device."""
+        ``serve.wait.*`` are the blocks on the device. ``serve.wait.decode``
+        is the block on the tokens of the step the call completes (its
+        decode's and its first tokens), taken after the next step is
+        dispatched; ``serve.wait.first_token`` is a first token read at
+        once (see ``chunk_exec.finish_prefill``)."""
         t = time.perf_counter()
         try:
             with jax.profiler.TraceAnnotation(name, **meta):
@@ -347,7 +366,10 @@ class Scheduler:
 
     @property
     def num_active(self) -> int:
-        return len(self._active)
+        """Requests holding a slot, and those that stopped while a step in
+        flight still carries a row of theirs."""
+        stopped = {rs.rid for ev in self._flights for _, rs in ev["rows"] if rs.done}
+        return len(self._active) + len(stopped)
 
     def result(self, rid: int) -> RequestState:
         rs = self._finished.get(rid)
@@ -378,7 +400,7 @@ class Scheduler:
             | {rs.rid for rs in self._preempted}
         )
         results: dict[int, RequestState] = {}
-        while self._queue or self._active or self._preempted:
+        while self._queue or self._active or self._preempted or self._flights:
             self.step()
             for rid in list(in_flight):
                 rs = self._finished.get(rid)
@@ -389,90 +411,200 @@ class Scheduler:
 
     # -- one scheduling iteration --------------------------------------------
     def step(self) -> bool:
-        """One plan → execute → observe iteration: admit/resume, stream at
-        most one prefill chunk, per-slot speculative verifies, then one
-        decode step over the remaining rows. The decisions taken are
-        published as `last_plan`. Returns True if any program ran."""
-        with self._phase("serve.step"):
-            self._ev = {
-                "admits": [], "chunk": None, "verifies": [], "rows": (),
-                "preempted": [],
-            }
-            try:
-                return self._step()
-            finally:
-                e = self._ev
-                self.last_plan = planlib.BatchPlan(
-                    admitted=tuple(e["admits"]), chunk=e["chunk"],
-                    verifies=tuple(e["verifies"]), decode_rows=e["rows"],
-                    preempted=tuple(e["preempted"]),
-                )
+        """Complete one step: its tokens reach the host and are emitted, and
+        `last_plan` describes it. Returns False when no step was left.
 
-    def _step(self) -> bool:
+        The call first plans and dispatches the next step — admit/resume,
+        at most one prefill chunk, per-slot speculative verifies, one
+        decode over the remaining rows — and only then reads the step
+        before it, so one step stays queued on the device. When the next
+        plan needs a token the device still holds (`_reads_first`), the
+        call completes the step in flight and dispatches nothing; the next
+        call plans with every token on the host."""
+        with self._phase("serve.step"):
+            self._done = False
+            self.last_plan = planlib.BatchPlan()
+            if self._flights and self._reads_first():
+                self._drain()
+            else:
+                self._advance()
+            return self._done
+
+    def _advance(self) -> None:
+        """Dispatch the next step, then complete the one before it. From
+        cold (nothing in flight) a second step is dispatched first when its
+        plan needs no token the first holds, so that one stays queued."""
+        cold = not self._flights
+        if self._dispatch() and cold and not self._reads_first():
+            self._dispatch()
+        if not self._done and self._flights:
+            self._complete(self._flights.popleft())
+
+    def _reads_first(self) -> bool:
+        """Whether the next plan needs a token that may still be on the
+        device: a speculative verify drafts from the host's tokens, and a
+        whole-prompt admission reads its first token before the step goes
+        on. A preemption, which replays or restores its victim's tokens,
+        drains where it happens (``preempt.ensure_pages``)."""
+        if self._free_slots and any(
+            not admission.streams(self, rs) for rs in self._queue
+        ):
+            return True
+        return self._spec and any(
+            spec_exec.drafts(self, rs) for rs in self._active.values()
+        )
+
+    def _drain(self) -> bool:
+        """Complete the step in flight before the plan goes on; False when
+        none is."""
+        if not self._flights:
+            return False
+        self.drained_steps += 1
+        self._complete(self._flights.popleft())
+        return True
+
+    @staticmethod
+    def _new_record() -> dict[str, Any]:
+        """An empty record of one step: its decisions and, once dispatched,
+        the device arrays its completion reads."""
+        return {
+            "admits": [], "chunk": None, "verifies": [], "rows": (),
+            "preempted": [], "firsts": [], "toks": None, "logits": None,
+            "pages": None,
+        }
+
+    def _dispatch(self) -> bool:
+        """Plan one step and put its programs on the device's queue; the
+        step joins `_flights` if it decided anything. What needs no token
+        value — positions, page growth, ``length`` finishes — is settled
+        here; `_complete` reads the tokens."""
+        ev = self._ev = self._new_record()
         with self._phase("serve.admit"):
             self._admit_pending()
-        ran = False
         if self._chunked:
-            ran = self._prefill_chunk_step()
+            self._prefill_chunk_step()
         handled: set[int] = set()
         if self._spec and self._active_mask.any():
             handled = self._spec_step()
-            ran = ran or bool(handled)
         # Slots that already emitted via verify sit out this decode: their
         # cleared mask freezes pos and per-slot states exactly like a
         # PREFILLING slot's.
         rows = self._plan(planlib.decode_rows, self._active_mask, handled)
-        self._ev["rows"] = rows
-        if not rows:
-            return ran
-        if self._paged:
+        if rows and self._paged:
             with self._phase("serve.pages"):
                 self._grow_pages(skip=handled)
+                # Growth may preempt a row, or complete the step in flight
+                # and finish one.
+                rows = tuple(r for r in rows if self._active_mask[r])
                 if self._sharing:
-                    # CoW guard: decode writes one token per ACTIVE slot at
-                    # its current position — fork first if that page is
-                    # shared (the scheduler's write pattern keeps this a
-                    # no-op, but the invariant is enforced here, not
-                    # assumed).
-                    for slot, rs in list(self._active.items()):
-                        if rs.status is RequestStatus.ACTIVE and slot not in handled:
-                            p = int(self._pos_host[slot])
-                            self._apply_cow(self.mem.prepare_write(slot, p, p + 1))
+                    # CoW guard: decode writes one token per row at its
+                    # current position — fork first if that page is shared
+                    # (the scheduler's write pattern keeps this a no-op, but
+                    # the invariant is enforced here, not assumed).
+                    for slot in rows:
+                        p = int(self._pos_host[slot])
+                        self._apply_cow(self.mem.prepare_write(slot, p, p + 1))
                 self._states["page_table"] = self._put(self.mem.pt)
                 if self.decode_pages_table is not None:
-                    self._count_decode_pages()
+                    ev["pages"] = self._decode_pages()
+        if rows:
+            self._dispatch_decode(rows)
+        if not any(ev[k] for k in ("admits", "chunk", "verifies", "rows", "preempted")):
+            return False
+        self._flights.append(ev)
+        return True
 
+    def _dispatch_decode(self, rows: tuple[int, ...]) -> None:
+        """Dispatch the decode over ``rows`` and the sample that writes
+        their tokens into the next-input vector; nothing is read."""
+        ev = self._ev
         with self._phase("serve.decode"):
-            mask = np.zeros_like(self._active_mask)
+            if self._flights:
+                self.overlapped_steps += 1
+            n = self.sched.n_slots
+            mask = np.zeros(n, bool)
             mask[list(rows)] = True
             self._key, sub = jax.random.split(self._key)
             logits, self._states = self.programs.decode(
-                self.params, self._states, self._put(self._tokens), self._put(mask)
+                self.params, self._states, self._tok, self._put(mask)
             )
-            self.last_decode_logits = logits
-            cols = self.programs.sample(
-                logits[:, -1, :], jnp.asarray(self._temps), sub
+            self._tok = self.programs.sample(
+                logits[:, -1, :], self._put(self._temps), sub, self._tok,
+                self._put(np.where(mask, np.arange(n), n).astype(np.int32)),
             )
-        with self._phase("serve.wait.decode"):
-            cols = np.asarray(cols)
-        self.total_decode_steps += 1
-
-        with self._phase("serve.emit"):
-            now = time.perf_counter()
-            for slot, rs in list(self._active.items()):
-                if rs.status is not RequestStatus.ACTIVE or slot in handled:
-                    continue  # still streaming its prompt in, or emitted via spec
+            ev["toks"], ev["logits"] = self._tok, logits
+            ev["rows"] = tuple((slot, self._active[slot]) for slot in rows)
+            for slot, rs in ev["rows"]:
                 rs.decode_steps += 1
                 self._pos_host[slot] += 1
-                tok = int(cols[slot])
-                rs.tokens.append(tok)
-                rs.t_tokens.append(now)
-                self._tokens[slot, 0] = tok
-                self._maybe_finish(rs, now)
-        return True
+                self._queue_token(rs)
 
-    def _count_decode_pages(self) -> None:
-        """Add this decode step's pages to ``decode_pages_walked`` and
+    def _queue_token(self, rs: RequestState) -> None:
+        """Count a token sampled for ``rs`` on the device. Once its last
+        token is sampled the request decodes no more (its ``length`` finish
+        is known before the token is read): it keeps its slot and pages
+        until then, while its table row points at trash, as a retired
+        slot's does."""
+        rs.queued += 1
+        if len(rs.tokens) + rs.queued >= rs.request.max_new_tokens:
+            self._active_mask[rs.slot] = False
+            if self._paged:
+                self.mem.unmap(rs.slot)
+
+    def _place(self, slot: int, tok: int) -> None:
+        """Write a token the host knows into ``slot``'s next decode input."""
+        self._tok = self.programs.place(
+            self._tok, jnp.asarray(slot, jnp.int32), jnp.asarray(tok, jnp.int32)
+        )
+
+    def _complete(self, ev: dict[str, Any]) -> None:
+        """Read a dispatched step's tokens and emit them — each first token
+        sampled at the step's last prompt chunk, then the decode's — and
+        publish the step as `last_plan`. A row of a request that stopped on
+        an earlier token is dropped."""
+        firsts = ev["firsts"]
+        toks, got = ev["toks"], []
+        if toks is not None or firsts:
+            with self._phase("serve.wait.decode"):
+                toks, got = jax.device_get(
+                    (toks, [(vec, lg) for _, _, vec, lg in firsts])
+                )
+        emitted = []
+        with self._phase("serve.emit"):
+            now = time.perf_counter()  # the tokens are on the host
+            for (rs, slot, _, _), (vec, lg) in zip(firsts, got):
+                rs.queued -= 1
+                rs.prefill_logits = lg
+                self._emit(rs, int(vec[slot, 0]), now)
+            for slot, rs in ev["rows"]:
+                rs.queued -= 1
+                if not rs.done:
+                    self._emit(rs, int(toks[slot, 0]), now)
+                    emitted.append(slot)
+        if ev["toks"] is not None:
+            self.total_decode_steps += 1
+            if emitted:
+                self.last_decode_logits = ev["logits"]
+        if ev["pages"] is not None:
+            self.decode_pages_walked += ev["pages"][0]
+            self.decode_pages_table += ev["pages"][1]
+        self.last_plan = planlib.BatchPlan(
+            admitted=tuple(ev["admits"]), chunk=ev["chunk"],
+            verifies=tuple(ev["verifies"]), decode_rows=tuple(emitted),
+            preempted=tuple(ev["preempted"]),
+        )
+        self._done = True
+
+    def _emit(self, rs: RequestState, tok: int, now: float) -> None:
+        """A token of ``rs`` is on the host: record and stamp it."""
+        if not rs.tokens:
+            rs.t_first_token = now
+        rs.tokens.append(tok)
+        rs.t_tokens.append(now)
+        self._maybe_finish(rs, now)
+
+    def _decode_pages(self) -> tuple[int, int]:
+        """This decode step's pages for ``decode_pages_walked`` and
         ``decode_pages_table``, from the host mirrors of the page table and
         of the positions the step decodes at (no device read)."""
         mem = self.mem
@@ -480,8 +612,7 @@ class Scheduler:
             mem.pt[:, 0], self._pos_host, page=mem.page_size,
             n_lp=mem.max_pages, trash=self._trash_rows, xp=np,
         )
-        self.decode_pages_walked += int(walked.sum())
-        self.decode_pages_table += mem.pt.size
+        return int(walked.sum()), int(mem.pt.size)
 
     # -- chunked prefill (executor in serve/chunk_exec.py) -------------------
     def _prefill_chunk_step(self) -> bool:
@@ -597,7 +728,6 @@ class Scheduler:
         slot = rs.slot
         assert slot is not None
         self._active_mask[slot] = False
-        self._tokens[slot, 0] = 0
         del self._active[slot]
         heapq.heappush(self._free_slots, slot)
         self._pos_host[slot] = 0
@@ -625,6 +755,8 @@ class Scheduler:
             "generated_tokens": self.generated_tokens_total,
             "retained": len(self._finished),
             "decode_steps": self.total_decode_steps,
+            "overlapped_steps": self.overlapped_steps,
+            "drained_steps": self.drained_steps,
             "chunk_steps": self.total_chunk_steps,
             "spec_steps": self.total_spec_steps,
             "spec_replays": self.total_spec_replays,

@@ -18,6 +18,21 @@ from repro.serve import plan as planlib
 from repro.serve.request import RequestState, RequestStatus
 
 
+def drafts(s, rs: RequestState) -> bool:
+    """Whether ``rs`` speculates: an ACTIVE greedy request without modality
+    extras, with >= 1 token of budget beyond this step's guaranteed
+    emission (tokens still on the device count as emitted)."""
+    req = rs.request
+    return (
+        rs.status is RequestStatus.ACTIVE
+        and req.temperature <= 0.0
+        and not req.extras
+        and s._plan(
+            planlib.spec_budget, req.max_new_tokens, len(rs.tokens) + rs.queued
+        ) >= 1
+    )
+
+
 def spec_step(s) -> set[int]:
     """Draft + verify for every eligible ACTIVE slot (greedy only, no
     modality extras, >= 1 token of budget beyond this step's guaranteed
@@ -27,18 +42,17 @@ def spec_step(s) -> set[int]:
     handled: set[int] = set()
     for slot in sorted(s._active):
         rs = s._active.get(slot)
-        if rs is None or rs.status is not RequestStatus.ACTIVE:
-            continue  # may have been preempted by an earlier verify
+        # It may have been preempted by an earlier verify. A slot with a
+        # token still on the device (it joined the decode while the step
+        # before was in flight) decodes plainly: drafts need every token.
+        if rs is None or rs.queued or not drafts(s, rs):
+            continue
         req = rs.request
-        if req.temperature > 0.0 or req.extras:
-            continue
-        budget = s._plan(planlib.spec_budget, req.max_new_tokens, len(rs.tokens))
-        if budget < 1:
-            continue
         with s._phase("serve.verify", rid=rs.rid):
             ctx = np.concatenate(
                 [np.asarray(req.prompt, np.int32), np.asarray(rs.tokens, np.int32)]
             )
+            budget = planlib.spec_budget(req.max_new_tokens, len(rs.tokens))
             k = min(s.sched.draft_k, budget)
             draft = np.asarray(s._drafter.propose(ctx, k), np.int32).reshape(-1)[:k]
             if draft.size and verify_slot(s, slot, rs, draft):
@@ -54,9 +68,9 @@ def verify_slot(s, slot: int, rs: RequestState, draft: np.ndarray) -> bool:
     page backing.
 
     Invariant in and out: the cache holds ``prompt + generated - 1``
-    tokens and ``_tokens[slot]`` is the last generated token, not yet
-    fed. Greedy logits at chunk index ``i`` answer "what follows token
-    i", so index ``accepted`` supplies the bonus/correction token."""
+    tokens and the slot's next decode input is the last generated token,
+    not yet fed. Greedy logits at chunk index ``i`` answer "what follows
+    token i", so index ``accepted`` supplies the bonus/correction token."""
     vp = s._plan(
         planlib.plan_verify, slot, rs.rid, int(s._pos_host[slot]), len(draft),
         draft_k=s.sched.draft_k, mem=s.mem if s._paged else None,
@@ -81,7 +95,7 @@ def verify_slot(s, slot: int, rs: RequestState, draft: np.ndarray) -> bool:
     snap = s._states["layers"] if s._needs_replay else None
 
     toks = np.zeros(vp.bucket, np.int32)
-    toks[0] = s._tokens[slot, 0]
+    toks[0] = rs.tokens[-1]
     toks[1:n_real] = draft
     toks_dev = s._put(toks[None, :])
     slot_t = jnp.asarray(slot, jnp.int32)
@@ -155,10 +169,9 @@ def verify_slot(s, slot: int, rs: RequestState, draft: np.ndarray) -> bool:
     s.accepted_tokens_total += accept
     now = time.perf_counter()
     for tok in emitted:
-        rs.tokens.append(tok)
-        rs.t_tokens.append(now)
-        s._tokens[slot, 0] = tok
-        s._maybe_finish(rs, now)
+        s._emit(rs, tok, now)
         if rs.done:
             break  # stop token mid-run: drop the rest, as plain decode would
+    else:
+        s._place(slot, rs.tokens[-1])
     return True

@@ -532,9 +532,11 @@ class TestStatsAndEviction:
         if backend == "xla":
             assert walked == table == [None] * 6
             return
-        # a: 1 page a step; b: 2; c: 3; the sixth step decodes nothing.
-        assert np.diff([0] + walked).tolist() == [1 + 2, 1 + 2, 1 + 3, 1 + 3, 1 + 3, 0]
-        assert np.diff([0] + table).tolist() == [12, 12, 12, 12, 12, 0]
+        # a: 1 page a step; b: 2; c: 3. The third step was planned before c
+        # arrived (one step stays queued ahead), so c joins at the fourth;
+        # a slot whose last token is sampled walks no page after it.
+        assert np.diff([0] + walked).tolist() == [1 + 2, 1 + 2, 1, 1 + 3, 1 + 3, 3]
+        assert np.diff([0] + table).tolist() == [12, 12, 12, 12, 12, 12]
 
     def test_result_of_inflight_request(self):
         cfg, params = _params_for("llama3.2-3b")

@@ -58,15 +58,24 @@ class TestConcurrentStreamers:
                             chunk_budget=16, preemption=policy),
         )
         rids = [sched.submit(Request(p, max_new_tokens=8)) for p in prompts]
+        # Count the streamers as each step plans its chunk: between step()
+        # calls the host's view already runs one step ahead.
         both_streaming = 0
-        for _ in range(500):
-            if not (sched.pending or sched.num_active):
-                break
+        chunk_step = sched._prefill_chunk_step
+
+        def counting_chunk_step():
+            nonlocal both_streaming
             n_prefilling = sum(
                 rs.status is RequestStatus.PREFILLING
                 for rs in sched._active.values()
             )
             both_streaming = max(both_streaming, n_prefilling)
+            return chunk_step()
+
+        sched._prefill_chunk_step = counting_chunk_step
+        for _ in range(500):
+            if not (sched.pending or sched.num_active):
+                break
             sched.step()
         else:
             pytest.fail("two-streamer drain did not terminate in 500 steps")
@@ -92,8 +101,9 @@ class TestConcurrentStreamers:
                             prefix_sharing=False),
         )
         r0 = sched.submit(Request(prompts[0], max_new_tokens=6))
-        sched.step()
         r1 = sched.submit(Request(prompts[1], max_new_tokens=6))
+        # From cold one step() dispatches two steps, each a chunk of r0's
+        # 40 tokens (16 at a time): both prompts are still streaming.
         sched.step()
         slots = {rs.rid: slot for slot, rs in sched._active.items()}
         assert all(

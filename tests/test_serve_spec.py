@@ -186,19 +186,25 @@ class TestAcceptRollback:
         position, same page table and refcounts."""
         cfg, params, prompt, _ = setup
 
-        def build(spec):
+        def build():
             s = Scheduler(
                 cfg, params, ShardingCtx.null(),
                 SchedulerConfig(n_slots=1, cache_len=64, page_size=8,
-                                chunk_budget=16, speculative=spec, draft_k=4),
+                                chunk_budget=16, speculative=True, draft_k=4),
             )
             rid = s.submit(Request(prompt, max_new_tokens=8))
             s.step()  # prefill + first token
             return s, rid
 
-        spec, rid_s = build(True)
-        plain, rid_p = build(False)
+        # The plain side speculates too, with a drafter that never proposes:
+        # its step is a plain decode, read before the next step is planned,
+        # like the verify step it is compared with. Without speculation the
+        # next step would already be dispatched, its positions and pages
+        # one step ahead.
+        spec, rid_s = build()
+        plain, rid_p = build()
         spec.set_drafter(ScriptDrafter([np.full(4, -2, np.int32)]))
+        plain.set_drafter(ScriptDrafter([]))
         spec.step()
         plain.step()
         assert spec.total_spec_steps == 1

@@ -18,9 +18,11 @@ from repro.serve.scheduler import Scheduler, SchedulerConfig
 from repro.sharding.rules import ShardingCtx
 
 # The spans of a chunked step that streams a prompt's last chunk and decodes.
+# Its first token is read with the step's decode tokens, in
+# ``serve.wait.decode``: no ``serve.wait.first_token``.
 CHUNKED_SPANS = {
-    "serve.step", "serve.admit", "serve.chunk", "serve.wait.first_token",
-    "serve.pages", "serve.decode", "serve.wait.decode", "serve.emit",
+    "serve.step", "serve.admit", "serve.chunk", "serve.pages",
+    "serve.decode", "serve.wait.decode", "serve.emit",
 }
 
 
